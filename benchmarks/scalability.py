@@ -10,7 +10,7 @@
     HLO: the comm cost of the full Cluster-aware Graph Parallelism
     composition, not just the dense a2a primitive.
 
-All mesh/shard_map construction goes through repro.compat (JAX 0.4.x+).
+All mesh/shard_map construction goes through repro.compat.
 """
 
 from __future__ import annotations

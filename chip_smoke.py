@@ -1,0 +1,276 @@
+#!/usr/bin/env python3
+"""Chip smoke: graph-transformer training on a TPU through the normal entry
+point, ``repro.launch.train.main``, in this one process.
+
+  python chip_smoke.py               # one chip: graphormer_slim, then gt
+  python chip_smoke.py --four-chips  # 4 chips: the sharded path vs 1 chip
+
+One chip (no arguments):
+
+1. ``graphormer_slim``, published ``CONFIG`` (4L, d=64, 8 heads, 128
+   features, 40 classes), node task on a seeded SBM graph of
+   ``SLIM_NODES`` nodes, ``STEPS`` steps with the elastic loop on and
+   dense interleave steps. ``SLIM_NODES`` is the largest size tried whose
+   dense step fits one v5e chip per ``memory_analysis()`` (its
+   ``(1, H, S, S)`` f32 bias and that bias's gradient dominate).
+2. On the trained params and the same batch, the compiled-kernel sparse
+   loss and gradient norm against the ``ref`` (jnp oracle) path.
+3. ``gt``, published ``CONFIG``, on ``GT_NODES`` nodes (its Laplacian PE
+   is a dense host eigh).
+
+``--four-chips`` runs only ``graphormer_slim`` with ``--mesh-model 4``
+(Ulysses all-to-all around the cluster kernel) and the same steps on one
+chip as its comparison.
+
+Every check raises; nothing is caught. The last line of standard output
+is ``{"ok": true, "device": {...}}`` and is printed only when every check
+passed. Without a TPU the script exits non-zero before any phase.
+"""
+
+from __future__ import annotations
+
+import argparse
+import contextlib
+import io
+import json
+import math
+import os
+import sys
+import tempfile
+import time
+import warnings
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parent
+SLIM_NODES = 6912
+GT_NODES = 2047
+STEPS = 10
+INTERLEAVE = 8           # dense steps at 0 and 8 (the published period)
+# compiled-vs-ref tolerances (bf16 activations, f32 accumulation). bf16's
+# unit roundoff is 2^-8 = 3.9e-3 and the two paths round attention
+# outputs at different points through 4 layers: the loss averages over
+# thousands of nodes (errors average out) — allow about 2.5 roundoffs;
+# the gradient norm accumulates them through the backward chain — allow
+# about 13.
+LOSS_RTOL = 1e-2
+GRAD_NORM_RTOL = 5e-2
+# sharded-vs-one-chip per-step loss: same kernel arithmetic per head,
+# different f32 reduction order across shards, amplified by 10 optimizer
+# steps
+SHARD_LOSS_RTOL = 2e-2
+SMEM_BYTES = 1 << 20     # v5e scalar memory: holds the prefetch streams
+
+
+def _check(cond, msg: str):
+    if not cond:
+        raise RuntimeError(f"chip_smoke: {msg}")
+
+
+class _Tee(io.TextIOBase):
+    """Echo writes to the real stdout and keep a copy."""
+
+    def __init__(self, out):
+        self.out, self.buf = out, io.StringIO()
+
+    def write(self, s):
+        self.buf.write(s)
+        return self.out.write(s)
+
+    def flush(self):
+        self.out.flush()
+
+
+def _train(arch: str, nodes: int, *extra: str):
+    """One ``repro.launch.train.main`` run in a fresh checkpoint directory
+    (a stale run there would restore and take zero steps). Returns the
+    trainer, its final state (restored from the run's last checkpoint)
+    and what main printed."""
+    from repro.launch.train import main as train_main
+
+    with tempfile.TemporaryDirectory(prefix="chip_smoke_") as ckpt:
+        argv = ["--arch", arch, "--task", "node",
+                "--graph-nodes", str(nodes), "--steps", str(STEPS),
+                "--ckpt-dir", ckpt, "--ckpt-every", str(STEPS),
+                "--interleave-period", str(INTERLEAVE),
+                "--elastic-every", "1", *extra]
+        tee = _Tee(sys.stdout)
+        t0 = time.perf_counter()
+        with contextlib.redirect_stdout(tee):
+            trainer = train_main(argv)
+        print(f"[{arch}] train main: {time.perf_counter() - t0:.1f}s "
+              f"for {STEPS} steps incl. prep and compile")
+        state, at = trainer.restore_or_init()
+    _check(at == STEPS, f"{arch}: final checkpoint at step {at}, "
+                        f"not {STEPS}")
+    return trainer, state, tee.buf.getvalue()
+
+
+def _check_history(trainer, label: str):
+    h = trainer.history
+    _check(len(h) == STEPS, f"{label}: {len(h)} steps ran, {STEPS} asked")
+    losses = [r["loss"] for r in h]
+    _check(all(math.isfinite(x) for x in losses),
+           f"{label}: non-finite loss in {losses}")
+    _check(sum(r["skipped"] for r in h) == 0,
+           f"{label}: the non-finite guard skipped steps")
+    dense = sum(1 for r in h if r["dense"])
+    _check(trainer.task.conditions_ok,
+           f"{label}: layout failed C1-C3, every step would be dense")
+    print(f"[{label}] losses={losses} dense_steps={dense} "
+          f"step_seconds={[round(r['seconds'], 3) for r in h]}")
+    return losses, dense
+
+
+def _layout_line(trainer, label: str):
+    lay = trainer.task.layout
+    b = trainer.task.batches(0)
+    idx_bytes = b["block_idx"].size * 4
+    idxt_bytes = b["block_idx_t"].size * 4
+    print(f"[{label}] nodes={trainer.task.g.n} S={lay.seq_len} "
+          f"bq={lay.bq} bk={lay.bk} nq={lay.nq} mb={trainer.task.mb_cap} "
+          f"prefetch_bytes fwd/dq={idx_bytes} dkv={idxt_bytes} "
+          f"(SMEM {SMEM_BYTES})")
+    _check(max(idx_bytes, idxt_bytes) <= SMEM_BYTES // 2,
+           f"{label}: scalar-prefetch stream does not leave SMEM headroom")
+
+
+def _step_program(trainer, state, variant: str, label: str) -> str:
+    """Compile the run's jitted step again (the persistent cache makes it
+    cheap), print its memory analysis, return its HLO text."""
+    t0 = time.perf_counter()
+    compiled = trainer.lower(variant, state).compile()
+    m = compiled.memory_analysis()
+    print(f"[{label}] {variant} step: compile {time.perf_counter() - t0:.1f}s "
+          f"args={m.argument_size_in_bytes} temp={m.temp_size_in_bytes} "
+          f"out={m.output_size_in_bytes}")
+    return compiled.as_text()
+
+
+def _loss_and_grad_norm(model, params, batch, mode: str):
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels import ops as kops
+
+    kops.set_mode(mode)
+    loss_fn = model.loss_variants["sparse"]
+    fn = jax.jit(lambda p, b: jax.value_and_grad(
+        lambda q: loss_fn(q, b)[0])(p))
+    loss, grads = fn(params, batch)
+    norm = jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
+                        for g in jax.tree.leaves(grads)))
+    kops.set_mode("auto")
+    return float(loss), float(norm)
+
+
+def _rel(a: float, b: float) -> float:
+    return abs(a - b) / max(abs(b), 1e-12)
+
+
+def _peak_hbm(devices) -> list[int]:
+    return [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+            for d in devices]
+
+
+def one_chip():
+    from repro.kernels import ops as kops
+
+    table = kops.dispatch_table()
+    print(f"kernel dispatch: {table}")
+    _check(table["cluster_attention"] == "compiled",
+           f"cluster_attention dispatches {table['cluster_attention']!r}")
+
+    # phase 1: graphormer_slim (bucket-biased kernel + dense interleave)
+    trainer, state, _ = _train("graphormer_slim", SLIM_NODES)
+    _layout_line(trainer, "graphormer_slim")
+    _, dense = _check_history(trainer, "graphormer_slim")
+    _check(0 < dense < STEPS, f"graphormer_slim: {dense} dense steps of "
+                              f"{STEPS}; want both variants")
+    hlo = _step_program(trainer, state, "sparse", "graphormer_slim")
+    _check("tpu_custom_call" in hlo,
+           "graphormer_slim: no tpu_custom_call in the sparse step")
+    _step_program(trainer, state, "dense", "graphormer_slim")
+
+    # phase 2: compiled kernel vs the jnp oracle, same params and batch
+    batch = trainer.task.batches(0)
+    got = _loss_and_grad_norm(trainer.model, state["params"], batch,
+                              "compiled")
+    ref = _loss_and_grad_norm(trainer.model, state["params"], batch, "ref")
+    dl, dg = _rel(got[0], ref[0]), _rel(got[1], ref[1])
+    print(f"[graphormer_slim] compiled loss={got[0]!r} grad_norm={got[1]!r}"
+          f" | ref loss={ref[0]!r} grad_norm={ref[1]!r} | rel "
+          f"{dl:.3e} (tol {LOSS_RTOL}) {dg:.3e} (tol {GRAD_NORM_RTOL})")
+    _check(dl <= LOSS_RTOL and dg <= GRAD_NORM_RTOL,
+           "graphormer_slim: compiled kernel disagrees with the ref path")
+    del trainer, state, batch
+
+    # phase 3: gt (Laplacian PE, no bias table)
+    trainer, state, _ = _train("gt", GT_NODES)
+    _layout_line(trainer, "gt")
+    _check_history(trainer, "gt")
+    hlo = _step_program(trainer, state, "sparse", "gt")
+    _check("tpu_custom_call" in hlo, "gt: no tpu_custom_call in the step")
+
+
+def four_chips():
+    import jax
+
+    devices = jax.devices()
+    _check(len(devices) == 4, f"--four-chips needs 4 devices, "
+                              f"JAX sees {len(devices)}")
+    trainer, state, out = _train("graphormer_slim", SLIM_NODES,
+                                 "--mesh-model", "4")
+    _check("sharded_cluster_attention=on" in out,
+           "the sharded cluster-attention path is off")
+    peaks = _peak_hbm(devices)
+    print(f"[sharded] peak_bytes_in_use per device: {peaks}")
+    _check(min(peaks) > 0 and min(peaks) >= max(peaks) // 4,
+           "sharded run piled its memory on one device")
+    sharded, _ = _check_history(trainer, "sharded")
+    hlo = _step_program(trainer, state, "sparse", "sharded")
+    _check("all-to-all" in hlo and "tpu_custom_call" in hlo,
+           "sharded sparse step lacks all-to-all or tpu_custom_call")
+    del trainer, state
+
+    trainer, _, _ = _train("graphormer_slim", SLIM_NODES)
+    single, _ = _check_history(trainer, "one-chip")
+    worst = max(_rel(a, b) for a, b in zip(sharded, single))
+    print(f"[sharded vs one-chip] worst per-step loss rel diff {worst:.3e} "
+          f"(tol {SHARD_LOSS_RTOL})")
+    _check(worst <= SHARD_LOSS_RTOL,
+           "sharded losses disagree with the one-chip run")
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--four-chips", action="store_true",
+                    help="run only the 4-chip sharded path and its "
+                         "one-chip comparison")
+    args = ap.parse_args(argv)
+
+    import jax
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        print(f"chip_smoke: no TPU (JAX platform {dev.platform!r})",
+              file=sys.stderr)
+        return 1
+
+    # winner tables are gitignored working-directory state: dispatch must
+    # come from committed files only
+    os.environ["REPRO_TUNE"] = "0"
+    sys.path.insert(0, str(ROOT / "src"))
+    from repro.launch.compile_cache import enable_compile_cache
+
+    print(f"compile cache: {enable_compile_cache()}")
+    warnings.filterwarnings("error", message=r"repro\.kernels\.ops")
+
+    four_chips() if args.four_chips else one_chip()
+    print(json.dumps({"ok": True, "device": {
+        "platform": dev.platform, "kind": dev.device_kind,
+        "count": len(jax.devices())}}))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -96,7 +96,7 @@ def sharded_attention_report(p: int = 4, *, seq: int = 1024, heads: int = 8,
                          d_head + (-d_head % LANE), nq, mb, bk=bq,
                          per_graph=True, return_residuals=True)
     idx = np.broadcast_to(np.asarray(lay.block_idx, np.int32)[None],
-                          (1, nq, mb))
+                          (1, nq, mb)).reshape(-1)  # flat prefetch stream
     grid_findings = pallas_check.audit_grid(
         triple["grid"], triple["in_specs"], triple["out_specs"],
         triple["in_shapes"], triple["out_shapes"], scalar_prefetch=(idx,),

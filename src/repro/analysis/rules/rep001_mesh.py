@@ -2,11 +2,10 @@
 
 Origin: PR 1 (platform policy, ROADMAP.md). ``jax.make_mesh`` grew
 ``axis_types``, ``shard_map`` moved out of ``jax.experimental`` and
-renamed its replication-check kwarg, ``jax.sharding.use_mesh`` superseded
-``with mesh:`` — calling any of them directly breaks one end of the
-supported JAX range (0.4.37 → current). The shim feature-detects once at
-import; nothing outside ``src/repro/compat`` may touch the drifting
-spellings.
+renamed its replication-check kwarg, the ambient-mesh context moved from
+``with mesh:`` to ``jax.sharding.use_mesh`` to ``jax.set_mesh`` — these
+spellings drift across JAX releases, so they live in one place:
+nothing outside ``src/repro/compat`` may touch them.
 """
 
 from __future__ import annotations
@@ -19,6 +18,7 @@ from repro.analysis import lint
 _FORBIDDEN = {
     "jax.make_mesh": "jax.make_mesh",
     "jax.shard_map": "jax.shard_map",
+    "jax.set_mesh": "jax.set_mesh",
     "jax.sharding.use_mesh": "jax.sharding.use_mesh",
     "jax.sharding.Mesh": "raw jax.sharding.Mesh construction",
     "jax.experimental.shard_map": "jax.experimental.shard_map",
@@ -27,7 +27,7 @@ _FORBIDDEN = {
 
 # import spellings of the same drift surface
 _FORBIDDEN_IMPORT_FROM = {
-    "jax": {"make_mesh", "shard_map"},
+    "jax": {"make_mesh", "shard_map", "set_mesh"},
     "jax.sharding": {"use_mesh", "Mesh"},
     "jax.experimental": {"shard_map"},
     "jax.experimental.shard_map": {"shard_map"},
@@ -66,9 +66,8 @@ RULE = lint.Rule(
     code="REP001",
     title="mesh/shard_map construction must go through repro.compat",
     origin="PR 1",
-    fix_hint="use repro.compat.make_mesh / shard_map / use_mesh — the shim "
-             "feature-detects JAX API drift by signature (ROADMAP platform "
-             "policy)",
+    fix_hint="use repro.compat.make_mesh / shard_map / use_mesh — the one "
+             "home of the mesh spellings (ROADMAP platform policy)",
     applies=_applies,
     check=_check,
 )

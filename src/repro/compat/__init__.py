@@ -1,125 +1,49 @@
-"""JAX API-drift compatibility layer.
+"""The one home of mesh, shard_map and mesh-context calls.
 
 Every mesh / shard_map / mesh-context call in this repo goes through this
-module so the code runs unchanged on JAX 0.4.x through current:
+module, so each spelling lives in one place (the installed JAX, 0.9.0):
 
-* ``make_mesh``    — ``jax.make_mesh`` grew an ``axis_types`` kwarg (and
-  ``jax.sharding.AxisType``) after 0.4.x; older still is building
-  ``jax.sharding.Mesh`` from a device array by hand. One entry point,
-  feature-detected once at import.
-* ``shard_map``    — moved from ``jax.experimental.shard_map`` to
-  ``jax.shard_map``; its replication-check kwarg was renamed
-  ``check_rep`` -> ``check_vma``. We expose a single ``check=`` kwarg.
-* ``use_mesh``     — ``jax.sharding.use_mesh`` supersedes the
-  ``with mesh:`` context manager; we return whichever works.
-
-Policy: detect by signature (``inspect``), never by version string —
-backports and dev builds make version comparisons lie. Detection happens
-at import time so the per-call overhead is zero.
+* ``make_mesh``    — ``jax.make_mesh`` with ``AxisType.Auto`` on every
+  axis unless the caller passes ``axis_types``;
+* ``shard_map``    — ``jax.shard_map``, its ``check_vma`` replication
+  check exposed as ``check=``;
+* ``use_mesh``     — ``jax.set_mesh``, the ambient-mesh context.
 """
 
 from __future__ import annotations
 
-import inspect
-
 import jax
-import numpy as np
+from jax.sharding import AxisType
 
-__all__ = ["JAX_VERSION", "AxisType", "auto_axis_types", "make_mesh",
-           "shard_map", "use_mesh"]
-
-
-def _version_tuple(v: str):
-    out = []
-    for part in v.split(".")[:3]:
-        digits = "".join(ch for ch in part if ch.isdigit())
-        out.append(int(digits) if digits else 0)
-    return tuple(out)
-
-
-JAX_VERSION = _version_tuple(jax.__version__)
-
-# Present on newer JAX only; None on 0.4.x. Exposed so callers can gate
-# Auto/Explicit-mode features instead of touching jax.sharding directly.
-AxisType = getattr(jax.sharding, "AxisType", None)
-
-
-def auto_axis_types(n: int):
-    """(AxisType.Auto,) * n on JAX versions that have axis types, else
-    None (the only behaviour 0.4.x supports is Auto everywhere)."""
-    if AxisType is None:
-        return None
-    return (AxisType.Auto,) * n
-
-
-_MAKE_MESH = getattr(jax, "make_mesh", None)
-_MAKE_MESH_HAS_AXIS_TYPES = (
-    _MAKE_MESH is not None
-    and "axis_types" in inspect.signature(_MAKE_MESH).parameters)
+__all__ = ["make_mesh", "shard_map", "use_mesh"]
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None, axis_types="auto"):
-    """Version-portable ``jax.make_mesh``.
-
-    ``axis_types="auto"`` requests Auto sharding on every axis (a no-op
-    spelling on JAX versions without axis types); pass an explicit tuple
-    to forward one, or None to take the version default.
-    """
+    """``jax.make_mesh``. ``axis_types="auto"`` requests Auto sharding on
+    every axis; pass an explicit tuple to forward one, or None to take
+    JAX's default."""
     axis_shapes = tuple(int(s) for s in axis_shapes)
     axis_names = tuple(axis_names)
     if len(axis_shapes) != len(axis_names):
         raise ValueError(f"{len(axis_shapes)} axis sizes for "
                          f"{len(axis_names)} names")
-    if _MAKE_MESH_HAS_AXIS_TYPES:
-        if axis_types == "auto":
-            axis_types = auto_axis_types(len(axis_names))
-        kw = {} if axis_types is None else {"axis_types": axis_types}
-        if devices is not None:
-            kw["devices"] = devices
-        return _MAKE_MESH(axis_shapes, axis_names, **kw)
-    if _MAKE_MESH is not None:
-        kw = {"devices": devices} if devices is not None else {}
-        return _MAKE_MESH(axis_shapes, axis_names, **kw)
-    # oldest fallback: raw Mesh over the first prod(shape) devices
-    devs = np.asarray(devices if devices is not None else jax.devices())
-    n = int(np.prod(axis_shapes))
-    if devs.size < n:
-        raise ValueError(f"mesh {axis_shapes} needs {n} devices, "
-                         f"have {devs.size}")
-    return jax.sharding.Mesh(devs.reshape(-1)[:n].reshape(axis_shapes),
-                             axis_names)
-
-
-def _resolve_shard_map():
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn  # noqa: F811
-    params = inspect.signature(fn).parameters
-    check_kw = ("check_vma" if "check_vma" in params
-                else "check_rep" if "check_rep" in params else None)
-    return fn, check_kw
-
-
-_SHARD_MAP, _CHECK_KW = _resolve_shard_map()
+    if axis_types == "auto":
+        axis_types = (AxisType.Auto,) * len(axis_names)
+    kw = {} if axis_types is None else {"axis_types": axis_types}
+    if devices is not None:
+        kw["devices"] = devices
+    return jax.make_mesh(axis_shapes, axis_names, **kw)
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
-    """Version-portable shard_map. ``check`` maps onto check_vma /
-    check_rep (whichever this JAX spells); this repo always passes False —
-    the collectives here (a2a, psum of int payloads, ppermute schedules)
-    trip the replication checker's conservatism on several versions."""
-    kw = {_CHECK_KW: check} if _CHECK_KW is not None else {}
-    return _SHARD_MAP(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
-
-
-_USE_MESH = getattr(jax.sharding, "use_mesh", None)
+    """``jax.shard_map`` with ``check`` mapped onto ``check_vma``; this
+    repo always passes False — the collectives here (a2a, psum of int
+    payloads, ppermute schedules) trip the replication checker's
+    conservatism."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
 def use_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh:
-    ``jax.sharding.use_mesh`` where it exists, else the classic
-    ``with mesh:`` (Mesh is its own context manager on 0.4.x)."""
-    if _USE_MESH is not None:
-        return _USE_MESH(mesh)
-    return mesh
+    """Context manager installing ``mesh`` as the ambient mesh."""
+    return jax.set_mesh(mesh)

@@ -132,10 +132,15 @@ def dense_bias_from_buckets(dense_buckets, bias_table, n_heads: int):
         bk = bk[None]
     if bias_table is None:
         return jnp.zeros((bk.shape[0], n_heads) + bk.shape[1:], F32)
-    idx = jnp.maximum(bk, 0).astype(jnp.int32)
-    vals = jnp.take(bias_table.astype(F32), idx, axis=1)    # (H, B, S, S)
-    vals = jnp.moveaxis(vals, 0, 1)                         # (B, H, S, S)
-    return jnp.where((bk >= 0)[:, None], vals, 0.0)
+    # one compare-select per (static, small) bucket keeps S minor; a
+    # gather along the bucket axis yields an (S, S, H) layout whose H
+    # minor dim the TPU pads to 128 lanes (16x the bias at H = 8)
+    table = bias_table.astype(F32)
+    bk = bk[:, None]                                        # (B, 1, S, S)
+    out = jnp.zeros(bk.shape[:1] + table.shape[:1] + bk.shape[2:], F32)
+    for j in range(table.shape[1]):
+        out = jnp.where(bk == j, table[None, :, j, None, None], out)
+    return out                                              # (B, H, S, S)
 
 
 def dense_bias_from_layout(layout, bias_table, n_heads: int):

@@ -19,9 +19,13 @@ each kernel rebuilds its block's scores from q/k and the residual:
   production path threads the tight host-built one so re-reformation
   swaps both layouts with zero retraces.
 * **epilogue** — GQA head groups reduce onto the KV heads, and the
-  in-kernel bucketed ``dS`` partials (a one-hot segment-sum contraction
-  per block) collapse over graphs and q-rows to the ``(H, n_buckets)``
-  ``bias_table`` gradient.
+  in-kernel bucketed ``dS`` partials (one masked reduction of ``dS`` per
+  bucket and block) collapse over graphs and q-rows to the
+  ``(H, n_buckets)`` ``bias_table`` gradient.
+
+Residuals ``lse``/``delta`` are ``(B*H, S, 1)`` and the dbias partials
+``(B, H, nq, 1, n_buckets)``: every block's last two dims are either
+tile-aligned or the array's own, which the TPU lowering requires.
 
 ``ds = p * (dp - delta)`` with ``delta = rowsum(dO * O)`` — the standard
 flash backward identity; ``p = exp(s - lse)`` is already normalized
@@ -103,16 +107,18 @@ def _causal_mask(s, qi, ki, block_q, block_k):
     return jnp.where(qpos >= kpos, s, NEG_INF)
 
 
-def _bucket_bias(bkt_ref, bias_ref, h, s, block_q, block_k,
-                 fuse_bias=False):
-    bkt = bkt_ref[...].reshape(block_q, block_k).astype(jnp.int32)
-    table = bias_ref[h]
-    if fuse_bias:
-        # mirror of the forward's fused lookup: the operand carries the
-        # sentinel NEG_INF column, masked bkt = -1 wraps onto it
-        return bkt, s + jnp.take(table, bkt, axis=0, mode="wrap")
-    bias = jnp.take(table, jnp.maximum(bkt, 0), axis=0, mode="clip")
-    return bkt, jnp.where(bkt >= 0, s + bias, NEG_INF)
+def _bucket_sums(bkt, ds, n_buckets):
+    """``(1, n_buckets)`` per-bucket sums of a ``(bq, bk)`` ``dS`` tile,
+    bucket ids clipped into ``[0, n_buckets)`` like the forward lookup:
+    one masked two-axis reduction per bucket (``n_buckets`` is static and
+    small), placed into its lane by a compare-select."""
+    bc = jnp.clip(bkt, 0, n_buckets - 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, n_buckets), 1)
+    out = jnp.zeros((1, n_buckets), F32)
+    for j in range(n_buckets):
+        part = jnp.where(bc == j, ds, 0.0).sum(axis=0, keepdims=True)
+        out = jnp.where(lane == j, part.sum(axis=1, keepdims=True), out)
+    return out
 
 
 def _dq_kernel(idx_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
@@ -127,7 +133,7 @@ def _dq_kernel(idx_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
     def _init():
         acc_s[...] = jnp.zeros_like(acc_s)
 
-    blk = idx_ref[b, qi, mi]
+    blk = idx_ref[_ca.flat_slot(b, qi, mi, pl.num_programs(2), mb)]
 
     @pl.when(blk >= 0)
     def _compute():
@@ -136,11 +142,11 @@ def _dq_kernel(idx_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
         if causal:
             s = _causal_mask(s, qi, blk, block_q, block_k)
         do = do_ref[0].astype(F32)
-        p = jnp.exp(s - lse_ref[0][:, None])
+        p = jnp.exp(s - lse_ref[0])
         dp = jax.lax.dot_general(do, v_ref[0].astype(F32),
                                  (((1,), (1,)), ((), ())),
                                  preferred_element_type=F32)
-        ds = p * (dp - dl_ref[0][:, None])
+        ds = p * (dp - dl_ref[0])
         acc_s[...] += sm_scale * jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())), preferred_element_type=F32)
 
@@ -151,7 +157,7 @@ def _dq_kernel(idx_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
 
 def _dq_kernel_biased(idx_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
                       bkt_ref, bias_ref, dq_ref, db_ref, acc_s, db_s, *,
-                      sm_scale, block_q, block_k, n_buckets,
+                      sm_scale, block_q, block_k, n_buckets, width,
                       hoist_scale=False, fuse_bias=False):
     # no causal branch: the biased FORWARD kernel has none (masking lives
     # in the buckets; ops.py rejects causal+buckets), and the backward
@@ -167,38 +173,33 @@ def _dq_kernel_biased(idx_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
         acc_s[...] = jnp.zeros_like(acc_s)
         db_s[...] = jnp.zeros_like(db_s)
 
-    blk = idx_ref[b, qi, mi]
+    blk = idx_ref[_ca.flat_slot(b, qi, mi, pl.num_programs(2), mb)]
 
     @pl.when(blk >= 0)
     def _compute():
         q, k, s = _recompute_scores(q_ref, k_ref, sm_scale, block_q,
                                     block_k, hoist_scale)
-        bkt, s = _bucket_bias(bkt_ref, bias_ref, h, s, block_q, block_k,
-                              fuse_bias)
+        bkt, s = _ca.apply_bucket_bias(s, bkt_ref, bias_ref, h, block_q,
+                                       block_k, width, fuse_bias)
         do = do_ref[0].astype(F32)
-        p = jnp.exp(s - lse_ref[0][:, None])
+        p = jnp.exp(s - lse_ref[0])
         dp = jax.lax.dot_general(do, v_ref[0].astype(F32),
                                  (((1,), (1,)), ((), ())),
                                  preferred_element_type=F32)
-        ds = p * (dp - dl_ref[0][:, None])
+        ds = p * (dp - dl_ref[0])
         acc_s[...] += sm_scale * jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())), preferred_element_type=F32)
-        # bucket the raw dS (masked entries have p = 0 => ds = 0) with a
-        # single one-hot contraction at the ORIGINAL n_buckets width —
-        # under fuse_bias the bias OPERAND is one sentinel column wider,
-        # but the sentinel never receives gradient (masked ds = 0) and
-        # the returned dbias keeps the caller's table width
-        bc = jnp.clip(bkt, 0, n_buckets - 1).reshape(block_q * block_k, 1)
-        one_hot = (bc == jax.lax.broadcasted_iota(
-            jnp.int32, (block_q * block_k, n_buckets), 1)).astype(F32)
-        db_s[...] += jax.lax.dot_general(
-            ds.reshape(1, block_q * block_k), one_hot,
-            (((1,), (0,)), ((), ())), preferred_element_type=F32)
+        # bucket the raw dS (masked entries have p = 0 => ds = 0) at the
+        # ORIGINAL n_buckets width — under fuse_bias the bias OPERAND is
+        # one sentinel column wider, but the sentinel never receives
+        # gradient (masked ds = 0) and the returned dbias keeps the
+        # caller's table width
+        db_s[...] += _bucket_sums(bkt, ds, n_buckets)
 
     @pl.when(mi == mb - 1)
     def _finalize():
         dq_ref[0] = acc_s[...].astype(dq_ref.dtype)
-        db_ref[0, 0, 0] = db_s[0]
+        db_ref[0, 0, 0] = db_s[...]
 
 
 # ---------------------------------------------------------- dK/dV kernel
@@ -216,7 +217,7 @@ def _dkv_kernel(idxt_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
         dk_s[...] = jnp.zeros_like(dk_s)
         dv_s[...] = jnp.zeros_like(dv_s)
 
-    qrow = idxt_ref[b, ki, ti, 0]
+    qrow = idxt_ref[2 * _ca.flat_slot(b, ki, ti, pl.num_programs(2), mt)]
 
     @pl.when(qrow >= 0)
     def _compute():
@@ -225,13 +226,13 @@ def _dkv_kernel(idxt_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
         if causal:
             s = _causal_mask(s, qrow, ki, block_q, block_k)
         do = do_ref[0].astype(F32)
-        p = jnp.exp(s - lse_ref[0][:, None])
+        p = jnp.exp(s - lse_ref[0])
         dv_s[...] += jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())), preferred_element_type=F32)
         dp = jax.lax.dot_general(do, v_ref[0].astype(F32),
                                  (((1,), (1,)), ((), ())),
                                  preferred_element_type=F32)
-        ds = p * (dp - dl_ref[0][:, None])
+        ds = p * (dp - dl_ref[0])
         dk_s[...] += sm_scale * jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())), preferred_element_type=F32)
 
@@ -243,7 +244,7 @@ def _dkv_kernel(idxt_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
 
 def _dkv_kernel_biased(idxt_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                        dl_ref, bkt_ref, bias_ref, dk_ref, dv_ref, dk_s,
-                       dv_s, *, sm_scale, block_q, block_k,
+                       dv_s, *, sm_scale, block_q, block_k, width,
                        hoist_scale=False, fuse_bias=False):
     # no causal branch — see _dq_kernel_biased
     b = pl.program_id(0)
@@ -257,22 +258,22 @@ def _dkv_kernel_biased(idxt_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         dk_s[...] = jnp.zeros_like(dk_s)
         dv_s[...] = jnp.zeros_like(dv_s)
 
-    qrow = idxt_ref[b, ki, ti, 0]
+    qrow = idxt_ref[2 * _ca.flat_slot(b, ki, ti, pl.num_programs(2), mt)]
 
     @pl.when(qrow >= 0)
     def _compute():
         q, k, s = _recompute_scores(q_ref, k_ref, sm_scale, block_q,
                                     block_k, hoist_scale)
-        _, s = _bucket_bias(bkt_ref, bias_ref, h, s, block_q, block_k,
-                            fuse_bias)
+        _, s = _ca.apply_bucket_bias(s, bkt_ref, bias_ref, h, block_q,
+                                     block_k, width, fuse_bias)
         do = do_ref[0].astype(F32)
-        p = jnp.exp(s - lse_ref[0][:, None])
+        p = jnp.exp(s - lse_ref[0])
         dv_s[...] += jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())), preferred_element_type=F32)
         dp = jax.lax.dot_general(do, v_ref[0].astype(F32),
                                  (((1,), (1,)), ((), ())),
                                  preferred_element_type=F32)
-        ds = p * (dp - dl_ref[0][:, None])
+        ds = p * (dp - dl_ref[0])
         dk_s[...] += sm_scale * jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())), preferred_element_type=F32)
 
@@ -305,7 +306,7 @@ def _cluster_bwd(q, k, v, g, out, lse, block_idx, buckets, bias_table,
     vt = jnp.moveaxis(v, 2, 1).reshape(B * KV, S, Dh)
     gt = jnp.moveaxis(g, 2, 1).reshape(B * H, S, Dh).astype(F32)
     ot = jnp.moveaxis(out, 2, 1).reshape(B * H, S, Dh).astype(F32)
-    delta = (gt * ot).sum(-1)                         # (B*H, S)
+    delta = (gt * ot).sum(-1, keepdims=True)          # (B*H, S, 1)
 
     idx = jnp.broadcast_to(
         block_idx.astype(jnp.int32) if per_graph
@@ -318,25 +319,27 @@ def _cluster_bwd(q, k, v, g, out, lse, block_idx, buckets, bias_table,
             else block_idx_t.astype(jnp.int32)[None],
             (B,) + block_idx_t.shape[-3:])
     mt = idxt.shape[2]
+    # both scalar-prefetch streams go in flat (see _ca.flat_slot): the
+    # (B, nk, mt, 2) transposed layout would take 64x its size of SMEM
+    idx = idx.reshape(-1)
+    idxt = idxt.reshape(-1)
 
-    qkv_do_specs = [
+    def visitor(b, ki, ti, idxt, col):
+        """Column ``col`` (0: q-row, 1: forward slot) of the transposed
+        layout's ``[b, ki, ti]`` entry, -1 padding clamped to 0."""
+        pos = 2 * _ca.flat_slot(b, ki, ti, nk, mt) + col
+        return jnp.maximum(idxt[pos], 0)
+
+    qkv_do_specs = _ca.qkv_specs(H, KV, nq, mb, bq, bk, Dh) + [
         pl.BlockSpec((1, bq, Dh),
                      lambda b, h, qi, mi, idx: (b * H + h, qi, 0)),
-        pl.BlockSpec((1, bk, Dh),
-                     lambda b, h, qi, mi, idx: (
-                         b * KV + h // G,
-                         jnp.maximum(idx[b, qi, mi], 0), 0)),
-        pl.BlockSpec((1, bk, Dh),
-                     lambda b, h, qi, mi, idx: (
-                         b * KV + h // G,
-                         jnp.maximum(idx[b, qi, mi], 0), 0)),
-        pl.BlockSpec((1, bq, Dh),
+        pl.BlockSpec((1, bq, 1),
                      lambda b, h, qi, mi, idx: (b * H + h, qi, 0)),
-        pl.BlockSpec((1, bq), lambda b, h, qi, mi, idx: (b * H + h, qi)),
-        pl.BlockSpec((1, bq), lambda b, h, qi, mi, idx: (b * H + h, qi)),
+        pl.BlockSpec((1, bq, 1),
+                     lambda b, h, qi, mi, idx: (b * H + h, qi, 0)),
     ]
     if with_bias:
-        # dbias (one-hot width, db output) stays at the ORIGINAL table
+        # dbias (bucket sums, db output) stays at the ORIGINAL table
         # width; under fuse_bias the bias OPERAND grows the sentinel
         # column, exactly like the forward launch
         nb = bias_table.shape[1]
@@ -351,33 +354,35 @@ def _cluster_bwd(q, k, v, g, out, lse, block_idx, buckets, bias_table,
             bkt_spec = pl.BlockSpec(
                 (1, 1, bq, bk), lambda b, h, qi, mi, idx: (qi, mi, 0, 0))
         bias_spec = pl.BlockSpec((H, nb_op),
-                                 lambda b, h, qi, mi, idx: (0, 0))
+                                 lambda b, h, qi, mi, idx: (0, 0),
+                                 memory_space=pltpu.SMEM)
         bias_args = (buckets, bias_op)
 
         _ca._PALLAS_CALLS[0] += 1
         dqt, db_part = pl.pallas_call(
             functools.partial(_dq_kernel_biased, sm_scale=sm_scale,
                               block_q=bq, block_k=bk, n_buckets=nb,
-                              hoist_scale=hoist_scale, fuse_bias=fuse_bias),
+                              width=nb_op, hoist_scale=hoist_scale,
+                              fuse_bias=fuse_bias),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1, grid=(B, H, nq, mb),
                 in_specs=qkv_do_specs + [bkt_spec, bias_spec],
                 out_specs=[
                     pl.BlockSpec((1, bq, Dh),
                                  lambda b, h, qi, mi, idx: (b * H + h, qi, 0)),
-                    pl.BlockSpec((1, 1, 1, nb),
-                                 lambda b, h, qi, mi, idx: (b, h, qi, 0)),
+                    pl.BlockSpec((1, 1, 1, 1, nb),
+                                 lambda b, h, qi, mi, idx: (b, h, qi, 0, 0)),
                 ],
                 scratch_shapes=[pltpu.VMEM((bq, Dh), F32),
                                 pltpu.VMEM((1, nb), F32)]),
             out_shape=[jax.ShapeDtypeStruct((B * H, S, Dh), q.dtype),
-                       jax.ShapeDtypeStruct((B, H, nq, nb), F32)],
+                       jax.ShapeDtypeStruct((B, H, nq, 1, nb), F32)],
             interpret=interpret,
         )(idx, qt, kt, vt, gt, lse, delta, *bias_args)
-        # epilogue: the bucketing already happened in-kernel (one-hot
-        # contraction per block); the (B, H, nq, nb) partials just
+        # epilogue: the bucketing already happened in-kernel (masked
+        # reductions per block); the (B, H, nq, 1, nb) partials just
         # collapse over graphs and q-rows onto the (H, n_buckets) table
-        dbias = db_part.sum(axis=(0, 2)).astype(bias_table.dtype)
+        dbias = db_part.sum(axis=(0, 2, 3)).astype(bias_table.dtype)
     else:
         _ca._PALLAS_CALLS[0] += 1
         dqt = pl.pallas_call(
@@ -398,23 +403,18 @@ def _cluster_bwd(q, k, v, g, out, lse, block_idx, buckets, bias_table,
 
     # dK/dV over the transposed layout: q/do/lse/delta blocks are selected
     # by the visiting q-row, k/v by the grid's own k-block position
+    def q_rows(width):
+        return pl.BlockSpec(
+            (1, bq, width), lambda b, h, ki, ti, idxt: (
+                b * H + h, visitor(b, ki, ti, idxt, 0), 0))
+
     dkv_in_specs = [
-        pl.BlockSpec((1, bq, Dh),
-                     lambda b, h, ki, ti, idxt: (
-                         b * H + h, jnp.maximum(idxt[b, ki, ti, 0], 0), 0)),
+        q_rows(Dh),
         pl.BlockSpec((1, bk, Dh),
                      lambda b, h, ki, ti, idxt: (b * KV + h // G, ki, 0)),
         pl.BlockSpec((1, bk, Dh),
                      lambda b, h, ki, ti, idxt: (b * KV + h // G, ki, 0)),
-        pl.BlockSpec((1, bq, Dh),
-                     lambda b, h, ki, ti, idxt: (
-                         b * H + h, jnp.maximum(idxt[b, ki, ti, 0], 0), 0)),
-        pl.BlockSpec((1, bq),
-                     lambda b, h, ki, ti, idxt: (
-                         b * H + h, jnp.maximum(idxt[b, ki, ti, 0], 0))),
-        pl.BlockSpec((1, bq),
-                     lambda b, h, ki, ti, idxt: (
-                         b * H + h, jnp.maximum(idxt[b, ki, ti, 0], 0))),
+        q_rows(Dh), q_rows(1), q_rows(1),
     ]
     dkv_out_specs = [
         pl.BlockSpec((1, bk, Dh),
@@ -428,18 +428,19 @@ def _cluster_bwd(q, k, v, g, out, lse, block_idx, buckets, bias_table,
             bkt_t_spec = pl.BlockSpec(
                 (1, 1, 1, bq, bk),
                 lambda b, h, ki, ti, idxt: (
-                    b, jnp.maximum(idxt[b, ki, ti, 0], 0),
-                    jnp.maximum(idxt[b, ki, ti, 1], 0), 0, 0))
+                    b, visitor(b, ki, ti, idxt, 0),
+                    visitor(b, ki, ti, idxt, 1), 0, 0))
         else:
             bkt_t_spec = pl.BlockSpec(
                 (1, 1, bq, bk),
                 lambda b, h, ki, ti, idxt: (
-                    jnp.maximum(idxt[b, ki, ti, 0], 0),
-                    jnp.maximum(idxt[b, ki, ti, 1], 0), 0, 0))
+                    visitor(b, ki, ti, idxt, 0),
+                    visitor(b, ki, ti, idxt, 1), 0, 0))
         bias_t_spec = pl.BlockSpec((H, nb_op),
-                                   lambda b, h, ki, ti, idxt: (0, 0))
+                                   lambda b, h, ki, ti, idxt: (0, 0),
+                                   memory_space=pltpu.SMEM)
         kernel = functools.partial(_dkv_kernel_biased, sm_scale=sm_scale,
-                                   block_q=bq, block_k=bk,
+                                   block_q=bq, block_k=bk, width=nb_op,
                                    hoist_scale=hoist_scale,
                                    fuse_bias=fuse_bias)
         in_specs = dkv_in_specs + [bkt_t_spec, bias_t_spec]

@@ -17,7 +17,8 @@ kernel or the pure-jnp oracle with identical semantics:
     path on the fake-device CPU mesh.
 ``compiled``
     The Pallas kernel compiled for TPU — the production path. Requires a
-    TPU backend; without one the op falls back to ``ref`` with a warning.
+    TPU backend; without one the op raises (a forced device path never
+    silently runs the oracle on the host).
 ``auto``
     ``compiled`` on TPU, ``ref`` elsewhere. The default.
 
@@ -36,9 +37,10 @@ Environment beats config on purpose: a test or an operator can force a
 path without editing any call site. ``dispatch_table()`` reports the
 effective mode per op for logging.
 
-Legality and fallback policy (never raise, always warn + fall back):
+Legality and fallback policy. ``compiled`` without a TPU backend raises
+``RuntimeError`` at call (trace) time. Every other illegal corner warns
+and falls back to the oracle:
 
-* ``compiled`` without a TPU backend -> ``ref``;
 * cluster block shapes that violate TPU tiling — ``bq``/``bk`` not a
   multiple of the fp32 sublane (8), or a sequence the block rows don't
   tile — -> ``ref`` (block sizes are baked into the layout, so they
@@ -164,10 +166,14 @@ def _fallback(op: str, reason: str):
         f"({reason})", RuntimeWarning, stacklevel=3)
 
 
-def _no_tpu(mode: str) -> str | None:
+def _require_tpu(op: str, mode: str):
+    """``compiled`` is a device path: without a TPU it raises instead of
+    quietly running the oracle on the host."""
     if mode == "compiled" and jax.default_backend() != "tpu":
-        return "mode=compiled but no TPU backend is attached"
-    return None
+        raise RuntimeError(
+            f"repro.kernels.ops: {op}: mode=compiled but no TPU backend is "
+            f"attached (backend={jax.default_backend()!r}); use "
+            f"'interpret' or 'ref' off the chip")
 
 
 def _nonfloat(q, k, v) -> str | None:
@@ -261,7 +267,8 @@ def flash_attention(q, k, v, *, causal=True, block_q=None, block_k=None):
     """Dense flash attention. q ``(B, Sq, H, Dh)``, k/v ``(B, Sk, KV, Dh)``.
     The Pallas path pads ragged sequence tails and non-lane-aligned head
     dims itself and is differentiable (``flash_attention_vjp``); a missing
-    TPU or non-float inputs force the ref fallback.
+    TPU raises under ``compiled``; non-float inputs force the ref
+    fallback.
 
     ``block_q``/``block_k`` default to the autotuner's answer for this
     shape bucket (winner table if one is installed, else
@@ -269,9 +276,8 @@ def flash_attention(q, k, v, *, causal=True, block_q=None, block_k=None):
     sizes while rewrite flags (``hoist_scale``) still come from the
     resolved schedule."""
     mode = resolve_mode("flash_attention")
-    reason = _no_tpu(mode)
-    if reason is None and mode != "ref":
-        reason = _nonfloat(q, k, v)
+    _require_tpu("flash_attention", mode)
+    reason = _nonfloat(q, k, v) if mode != "ref" else None
     if reason:
         _fallback("flash_attention", reason)
         mode = "ref"
@@ -304,9 +310,6 @@ def _cluster_illegal(q, k, v, block_idx, buckets, causal, mode, want_bq,
     the recomputation backward cannot serve (non-float inputs, a
     malformed transposed layout) is rejected here, at call time, so
     ``jax.grad`` falls back instead of raising mid-trace."""
-    reason = _no_tpu(mode)
-    if reason:
-        return reason
     if block_idx.ndim not in (2, 3):
         return f"block_idx must be (nq, mb) or (B, nq, mb), got " \
                f"{block_idx.ndim}-d"
@@ -413,7 +416,7 @@ def _grid_race_reason(q, k, block_idx, buckets, bias_table,
     findings = pallas_check.audit_grid(
         triple["grid"], triple["in_specs"], triple["out_specs"],
         triple["in_shapes"], triple["out_shapes"],
-        scalar_prefetch=(arr,), label="cluster_attention")
+        scalar_prefetch=(arr.reshape(-1),), label="cluster_attention")
     bad = _ir_errors(findings)
     if bad:
         return f"pallas grid audit: {bad[0].message}"
@@ -455,6 +458,7 @@ def cluster_attention(q, k, v, block_idx, buckets=None, bias_table=None,
     (derived in-trace at the dense bound when omitted; the ref path never
     needs it). Per-graph (3-D) layouts run as ONE batched pallas_call."""
     mode = resolve_mode("cluster_attention")
+    _require_tpu("cluster_attention", mode)
     sched = resolve_schedule("cluster_attention", seq_len=q.shape[1],
                              heads=q.shape[2], d_head=q.shape[3],
                              dtype=q.dtype)
@@ -471,7 +475,11 @@ def cluster_attention(q, k, v, block_idx, buckets=None, bias_table=None,
                             causal=causal, row_chunk=row_chunk, bq=bq, bk=bk)
 
     interpret = mode == "interpret"
-    fuse_bias = sched.fuse_bias and buckets is not None
+    # the fused lookup's sentinel column sits right after the table: a
+    # caller without a table gets the 1-wide zero table below, whose
+    # sentinel would mask every bucket id >= 1 — fuse only real tables
+    fuse_bias = sched.fuse_bias and buckets is not None \
+        and bias_table is not None
     block_idx = block_idx.astype(jnp.int32)
     if buckets is not None and bias_table is None:
         # zero bias; 1-wide table (bucket lookups clamp to row 0)
@@ -504,13 +512,14 @@ def paged_attention(q, k_pool, v_pool, block_tables, cache_len, *,
     The block-table gather has no Pallas kernel yet — ``ref`` serves
     every resolved mode; ``interpret``/``compiled`` warn and fall back so
     forcing Pallas process-wide (``REPRO_FORCE_PALLAS``) never silently
-    changes serving semantics."""
+    changes serving semantics (``compiled`` off a TPU raises, as for
+    every op)."""
     mode = resolve_mode("paged_attention")
+    _require_tpu("paged_attention", mode)
     if mode != "ref":
         _fallback("paged_attention",
-                  _no_tpu(mode)
-                  or "the paged block-table gather has no Pallas kernel "
-                     "yet (ref is the only implementation)")
+                  "the paged block-table gather has no Pallas kernel "
+                  "yet (ref is the only implementation)")
     return _ref.paged_attention_ref(q, k_pool, v_pool, block_tables,
                                     cache_len, q_offset=q_offset,
                                     window=window, n_global=n_global)
@@ -522,18 +531,17 @@ def ssd(x, dt, a, b, c, *, chunk=None):
     """Mamba2 SSD chunked scan. ``chunk`` defaults to the autotuner's
     answer for this shape bucket (winner table first, else
     ``DEFAULT_SCHEDULES``). Falls back to ref when the sequence is not
-    tiled by ``chunk`` or no TPU is attached for ``compiled``."""
+    tiled by ``chunk``; ``compiled`` without a TPU raises."""
     if chunk is None:
         sched = resolve_schedule("ssd", seq_len=x.shape[1],
                                  heads=x.shape[2], d_head=x.shape[3],
                                  dtype=x.dtype)
         chunk = _sched_field(sched, "chunk")
     mode = resolve_mode("ssd")
-    reason = _no_tpu(mode)
-    if reason is None and mode != "ref" and x.shape[1] % chunk:
-        reason = f"sequence {x.shape[1]} is not tiled by chunk {chunk}"
-    if reason:
-        _fallback("ssd", reason)
+    _require_tpu("ssd", mode)
+    if mode != "ref" and x.shape[1] % chunk:
+        _fallback("ssd", f"sequence {x.shape[1]} is not tiled by chunk "
+                         f"{chunk}")
         mode = "ref"
     if mode == "ref":
         return _ref.ssd_ref(x, dt, a, b, c, chunk)

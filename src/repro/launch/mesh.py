@@ -7,7 +7,7 @@ sequence parallelism for long-context cells).
 
 Defined as functions so importing this module never touches jax device
 state (jax locks the device count on first use). All construction goes
-through repro.compat so the same code runs on JAX 0.4.x through current.
+through repro.compat, the one home of the mesh spellings.
 """
 
 from __future__ import annotations

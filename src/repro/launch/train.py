@@ -214,7 +214,7 @@ def _graph_main(args, cfg, model):
     for m in task.moves:
         print(f"ladder move @ step {m.step}: pos={m.pos} "
               f"beta_thre={m.beta_thre:.4f} (LDR {m.ldr:+.2e})")
-    ev = task.eval(state["params"])
+    ev = trainer.evaluate(state["params"])
     if ev:
         print("eval: " + " ".join(f"{k}={v:.4f}" for k, v in ev.items()))
     print(f"status={status} final_loss={trainer.history[-1]['loss']:.4f} "
@@ -224,4 +224,7 @@ def _graph_main(args, cfg, model):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -17,7 +17,7 @@ recovered and what the recovery promises:
   shedding with the warm engine's trace budget staying 0.
 
 Any unrecovered fault makes ``run_chaos`` return a failing report (the
-CLI exits nonzero) — CI runs this at both JAX pins.
+CLI exits nonzero) — CI runs this.
 """
 
 from __future__ import annotations

@@ -328,16 +328,44 @@ class Trainer:
         one = np.float32(1.0)  # healthy-step fault operand
         for name, fn in self._steps.items():
             label = f"trainer:{name}"
+            if budget is not None:
+                hlo = self.lower(name, state, step).compile().as_text()
+                findings += audit_collectives(hlo, budget, label=label)
             with self._mesh_ctx():
-                if budget is not None:
-                    hlo = fn.lower(state, batch, one).compile().as_text()
-                    findings += audit_collectives(hlo, budget, label=label)
                 findings += audit_dtype_flow(
                     jax.make_jaxpr(fn)(state, batch, one), label=label)
         self.ir_findings = findings
         if errors(findings):
             raise IRAuditError(findings, label="trainer ir_audit")
         return findings
+
+    def lower(self, variant: str, state, step: int = 0):
+        """The jitted step of loss variant ``variant`` lowered on
+        ``state`` and the task's batch for ``step`` (healthy fault
+        operand), under the run's mesh — the program ``run`` executes.
+        ``.compile()`` it for its HLO text and memory analysis."""
+        with self._mesh_ctx():
+            return self._steps[variant].lower(
+                state, self.task.batches(step), np.float32(1.0))
+
+    @contextlib.contextmanager
+    def trace_ctx(self):
+        """The run's mesh and the recipe's sharding rules: the context
+        the steps trace in (``step_fn`` enters the rules itself). Code
+        that traces the model outside the steps enters it, so a sharded
+        run reaches the shard_map-wrapped attention: GSPMD cannot
+        partition a Pallas kernel."""
+        with self._mesh_ctx():
+            if self.mesh is None or self.recipe is None:
+                yield
+            else:
+                with axis_rules(self.recipe, self.mesh):
+                    yield
+
+    def evaluate(self, params) -> dict:
+        """``task.eval(params)`` traced as the steps are (``trace_ctx``)."""
+        with self.trace_ctx():
+            return self.task.eval(params)
 
     # ------------------------------------------------------------ loop
 

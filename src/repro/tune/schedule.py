@@ -202,7 +202,8 @@ def enumerate_schedules(op: str, case: dict) -> list[Schedule]:
         nq, mb = lay.block_idx.shape[-2:]
         bk = lay.buckets.shape[-1] if lay.buckets is not None else S // nq
         arr = np.broadcast_to(np.asarray(lay.block_idx, np.int32)
-                              .reshape((-1, nq, mb))[:1], (B, nq, mb))
+                              .reshape((-1, nq, mb))[:1],
+                              (B, nq, mb)).reshape(-1)  # flat stream
         nb = case.get("n_buckets", getattr(lay, "n_buckets", None))
         for fuse in (False, True):
             if fuse and nb is None:

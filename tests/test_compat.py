@@ -1,19 +1,11 @@
-"""Compat layer coverage: (a) every repro.* module imports on this JAX
-version, (b) 1-D and 2-D meshes build under 8 fake CPU devices, (c) the
-sharded cluster-sparse attention path matches the single-device jnp oracle
-on a 4-way model axis (the Cluster-aware Graph Parallelism composition),
-(d) the import-time feature detection resolves every drift shape it
-claims to — exercised against stubbed jax attributes + module reload,
-so both ends of the supported range are covered regardless of which JAX
-this container runs.
+"""Compat layer coverage: (a) every repro.* module imports, (b) 1-D and
+2-D meshes build under 8 fake CPU devices, (c) the sharded cluster-sparse
+attention path matches the single-device jnp oracle on a 4-way model axis
+(the Cluster-aware Graph Parallelism composition), (d) axis types and the
+ambient-mesh context take the installed JAX's spellings.
 
 Multi-device parts run in subprocesses (XLA_FLAGS must be set before jax
 initializes); single-device compat semantics run in-process."""
-
-import contextlib
-import importlib
-import sys
-import types
 
 import jax
 import jax.numpy as jnp
@@ -26,22 +18,14 @@ from repro import compat
 
 # --------------------------------------------------------------- in-process
 
-def test_version_detection():
-    assert len(compat.JAX_VERSION) == 3
-    assert compat.JAX_VERSION >= (0, 4, 0)
-    types = compat.auto_axis_types(2)
-    assert types is None or len(types) == 2
-
-
 def test_make_mesh_single_device():
     mesh = compat.make_mesh((1,), ("data",))
     assert mesh.shape == {"data": 1}
     with compat.use_mesh(mesh):
-        pass  # context enters/exits cleanly on every JAX version
+        pass  # context enters and exits cleanly
 
 
 def test_make_mesh_rejects_shape_name_mismatch():
-    import pytest
     with pytest.raises(ValueError):
         compat.make_mesh((1, 1), ("data",))
 
@@ -66,168 +50,22 @@ def test_sharded_cluster_attention_single_device_fallback():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-6)
 
 
-# ---------------------------------------- feature-detection edge cases
-#
-# The shim detects by signature at import time, so each case stubs the
-# relevant jax attribute and reloads repro.compat; the finally-block
-# restores the real attributes and reloads once more, leaving the
-# in-place-mutated module exactly as every other test expects it.
-
-_MISSING = object()
-
-
-@contextlib.contextmanager
-def _reloaded_compat(patches):
-    """``patches``: iterable of (obj, attr_name, value) — ``_MISSING``
-    deletes the attribute. Applies them, reloads repro.compat, restores
-    everything and reloads again on exit (even on failure)."""
-    saved = []
-    try:
-        for obj, name, val in patches:
-            saved.append((obj, name, getattr(obj, name, _MISSING)))
-            if val is _MISSING:
-                if hasattr(obj, name):
-                    delattr(obj, name)
-            else:
-                setattr(obj, name, val)
-        importlib.reload(compat)
-        yield compat
-    finally:
-        for obj, name, old in reversed(saved):
-            if old is _MISSING:
-                if hasattr(obj, name):
-                    delattr(obj, name)
-            else:
-                setattr(obj, name, old)
-        importlib.reload(compat)
-
-
-def test_version_tuple_parses_dev_builds():
-    assert compat._version_tuple("0.4.37") == (0, 4, 37)
-    assert compat._version_tuple("0.7.2.dev20+gdeadbeef") == (0, 7, 2)
-    assert compat._version_tuple("0.5") == (0, 5)
-
-
-def test_use_mesh_falls_back_to_mesh_context():
-    """No jax.sharding.use_mesh -> the mesh itself is the context
-    manager (the classic ``with mesh:`` of 0.4.x)."""
-    with _reloaded_compat([(jax.sharding, "use_mesh", _MISSING)]) as c:
-        assert c._USE_MESH is None
-        sentinel = object()
-        assert c.use_mesh(sentinel) is sentinel
-
-
-def test_use_mesh_prefers_jax_sharding_use_mesh():
-    def fake_use_mesh(mesh):
-        return ("ctx", mesh)
-
-    with _reloaded_compat([(jax.sharding, "use_mesh", fake_use_mesh)]) as c:
-        assert c.use_mesh("m") == ("ctx", "m")
-
-
-@pytest.mark.parametrize("kwarg", ["check_vma", "check_rep", None])
-def test_shard_map_kwarg_detection(kwarg):
-    """The replication-check kwarg is found by name — ``check=`` maps
-    onto check_vma (current), check_rep (0.4.x), or nothing at all."""
-    seen = {}
-
-    def make_stub():
-        if kwarg == "check_vma":
-            def stub(f, *, mesh, in_specs, out_specs, check_vma=True):
-                seen.update(kw=check_vma)
-                return f
-        elif kwarg == "check_rep":
-            def stub(f, *, mesh, in_specs, out_specs, check_rep=True):
-                seen.update(kw=check_rep)
-                return f
-        else:
-            def stub(f, *, mesh, in_specs, out_specs):
-                seen.update(kw=_MISSING)
-                return f
-        return stub
-
-    with _reloaded_compat([(jax, "shard_map", make_stub())]) as c:
-        assert c._CHECK_KW == kwarg
-        fn = c.shard_map(lambda x: x, mesh=None, in_specs=(),
-                         out_specs=())
-        assert fn(7) == 7
-        # the repo-wide policy default check=False reached the stub
-        assert seen["kw"] is (False if kwarg else _MISSING)
-
-
-def test_shard_map_experimental_import_fallback():
-    """No jax.shard_map at all -> the shim imports the 0.4.x home
-    jax.experimental.shard_map and still maps check= onto check_rep."""
-    seen = {}
-
-    def fake_sm(f, *, mesh, in_specs, out_specs, check_rep=True):
-        seen.update(check_rep=check_rep)
-        return f
-
-    mod = types.ModuleType("jax.experimental.shard_map")
-    mod.shard_map = fake_sm
-    old = sys.modules.get("jax.experimental.shard_map")
-    sys.modules["jax.experimental.shard_map"] = mod
-    try:
-        with _reloaded_compat([(jax, "shard_map", _MISSING)]) as c:
-            assert c._SHARD_MAP is fake_sm and c._CHECK_KW == "check_rep"
-            c.shard_map(lambda x: x, mesh=None, in_specs=(), out_specs=())
-            assert seen["check_rep"] is False
-    finally:
-        if old is None:
-            del sys.modules["jax.experimental.shard_map"]
-        else:
-            sys.modules["jax.experimental.shard_map"] = old
-
-
-def test_make_mesh_without_axis_types_kwarg():
-    """An older jax.make_mesh (no axis_types parameter) is called
-    without the kwarg — and explicit axis_types are silently legal to
-    request, since 0.4.x has exactly one behaviour (Auto)."""
-    def old_make_mesh(axis_shapes, axis_names, devices=None):
-        return ("old", axis_shapes, axis_names, devices)
-
-    with _reloaded_compat([(jax, "make_mesh", old_make_mesh)]) as c:
-        assert c._MAKE_MESH_HAS_AXIS_TYPES is False
-        assert c.make_mesh((2,), ("x",)) == ("old", (2,), ("x",), None)
-        assert c.make_mesh((2,), ("x",), devices=["d"]) \
-            == ("old", (2,), ("x",), ["d"])
-
-
 def test_make_mesh_forwards_explicit_axis_types():
-    def new_make_mesh(axis_shapes, axis_names, *, devices=None,
-                      axis_types=None):
-        return ("new", axis_shapes, axis_names, axis_types)
+    """Auto on every axis by default; an explicit tuple is forwarded."""
+    from jax.sharding import AxisType
 
-    with _reloaded_compat([(jax, "make_mesh", new_make_mesh)]) as c:
-        assert c._MAKE_MESH_HAS_AXIS_TYPES is True
-        out = c.make_mesh((1,), ("x",), axis_types=("explicit",))
-        assert out == ("new", (1,), ("x",), ("explicit",))
-        # axis_types=None takes the version default: kwarg omitted
-        out = c.make_mesh((1,), ("x",), axis_types=None)
-        assert out == ("new", (1,), ("x",), None)
+    mesh = compat.make_mesh((1,), ("x",))
+    assert tuple(mesh.axis_types) == (AxisType.Auto,)
+    mesh = compat.make_mesh((1,), ("x",), axis_types=(AxisType.Explicit,))
+    assert tuple(mesh.axis_types) == (AxisType.Explicit,)
 
 
-def test_make_mesh_raw_mesh_fallback():
-    """jax.make_mesh missing entirely -> a raw Mesh over the first
-    prod(shape) devices; too few devices is a clear ValueError instead
-    of a reshape crash."""
-    with _reloaded_compat([(jax, "make_mesh", _MISSING)]) as c:
-        assert c._MAKE_MESH is None
-        mesh = c.make_mesh((1,), ("x",))
-        assert dict(mesh.shape) == {"x": 1}
-        assert tuple(mesh.axis_names) == ("x",)
-        with pytest.raises(ValueError, match="needs 8 devices"):
-            c.make_mesh((8,), ("x",))
-
-
-def test_reload_restores_real_detection():
-    """After the stub tests the module is back on the real jax — the
-    guard that the save/restore dance actually restored everything."""
+def test_use_mesh_installs_ambient_mesh():
+    """``use_mesh`` is ``jax.set_mesh``: inside it the mesh is ambient."""
     mesh = compat.make_mesh((1,), ("data",))
-    assert mesh.shape == {"data": 1}
     with compat.use_mesh(mesh):
-        pass
+        assert dict(jax.sharding.get_abstract_mesh().shape) == {"data": 1}
+    assert not jax.sharding.get_abstract_mesh().shape
 
 
 # -------------------------------------------------------------- subprocess
