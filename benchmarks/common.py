@@ -20,6 +20,7 @@ from repro.core.graph_model import graph_loss, graph_predict  # noqa: E402
 from repro.data.graph_pipeline import prepare_node_task  # noqa: E402
 from repro.models import build  # noqa: E402
 from repro.optim.adamw import AdamW  # noqa: E402
+from repro.runtime.spans import span  # noqa: E402
 
 
 from repro.tune.cases import cluster_grad_case  # noqa: E402,F401
@@ -51,9 +52,11 @@ class GraphTrainBench:
                       seed=seed)
         rng = np.random.default_rng(seed)
         self.train_mask = rng.random(g.n) < 0.6
-        self.prep = prepare_node_task(g, cfg, bq=32, bk=32, d_b=8,
-                                      beta_thre=beta_thre,
-                                      train_mask=self.train_mask)
+        with span("repro.task.prep") as prep:
+            self.prep = prepare_node_task(g, cfg, bq=32, bk=32, d_b=8,
+                                          beta_thre=beta_thre,
+                                          train_mask=self.train_mask)
+        self.prep_seconds = prep.seconds
         self.batch = {k: jnp.asarray(v) for k, v in self.prep.batch.items()}
         # eval batch: all labels visible
         prep_all = prepare_node_task(g, cfg, bq=32, bk=32, d_b=8,

@@ -9,7 +9,7 @@ from benchmarks.common import GraphTrainBench, row
 def main(full=False):
     epochs = 60
     bench = GraphTrainBench(arch="graphormer_slim", n=1024)
-    prep_s = bench.prep.prep_seconds
+    prep_s = bench.prep_seconds
     hist, t_epoch, acc = bench.train("torchgt", epochs=epochs)
     total = t_epoch * epochs
     row("sec4e_preprocessing", prep_s * 1e6,

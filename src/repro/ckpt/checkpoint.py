@@ -41,6 +41,8 @@ import zlib
 import jax
 import numpy as np
 
+from repro.runtime.spans import span
+
 SEP = "/"
 
 
@@ -120,37 +122,41 @@ class Checkpointer:
         """``extra`` is a JSON-safe dict stored verbatim in the manifest —
         the elastic trainer keeps its AutoTuner/layout state there so a
         restart resumes the ladder (read back via ``load_extra``)."""
-        self.wait()
-        flat = _flatten(tree)
-        host = {k: np.asarray(jax.device_get(v)) for k, v in flat.items()}
+        with span("repro.ckpt.save"):
+            self.wait()
+            flat = _flatten(tree)
+            host = {k: np.asarray(jax.device_get(v))
+                    for k, v in flat.items()}
 
         codec = self.codec
 
         def write():
-            tmp = os.path.join(self.dir, f".tmp-{step:08d}")
-            final = os.path.join(self.dir, f"step_{step:08d}")
-            shutil.rmtree(tmp, ignore_errors=True)
-            os.makedirs(tmp)
-            manifest = {"step": step, "codec": codec, "leaves": {}}
-            if extra is not None:
-                manifest["extra"] = extra
-            for i, (k, v) in enumerate(host.items()):
-                fn = f"leaf_{i:05d}.npy.{codec}"
-                raw = v.tobytes()  # ml_dtypes handles bf16
-                with open(os.path.join(tmp, fn), "wb") as f:
-                    f.write(_compress(codec, raw))
-                manifest["leaves"][k] = {
-                    "file": fn, "shape": list(v.shape), "dtype": str(v.dtype),
-                    # lineage checksum of the raw (uncompressed) bytes —
-                    # restore verifies against this by default
-                    "crc32": zlib.crc32(raw)}
-            with open(os.path.join(tmp, "manifest.json"), "w") as f:
-                json.dump(manifest, f)
-            with open(os.path.join(tmp, "COMMITTED"), "w") as f:
-                f.write("ok")
-            shutil.rmtree(final, ignore_errors=True)
-            os.rename(tmp, final)
-            self._gc()
+            with span("repro.ckpt.write"):
+                tmp = os.path.join(self.dir, f".tmp-{step:08d}")
+                final = os.path.join(self.dir, f"step_{step:08d}")
+                shutil.rmtree(tmp, ignore_errors=True)
+                os.makedirs(tmp)
+                manifest = {"step": step, "codec": codec, "leaves": {}}
+                if extra is not None:
+                    manifest["extra"] = extra
+                for i, (k, v) in enumerate(host.items()):
+                    fn = f"leaf_{i:05d}.npy.{codec}"
+                    raw = v.tobytes()  # ml_dtypes handles bf16
+                    with open(os.path.join(tmp, fn), "wb") as f:
+                        f.write(_compress(codec, raw))
+                    manifest["leaves"][k] = {
+                        "file": fn, "shape": list(v.shape),
+                        "dtype": str(v.dtype),
+                        # lineage checksum of the raw (uncompressed)
+                        # bytes — restore verifies against this by default
+                        "crc32": zlib.crc32(raw)}
+                with open(os.path.join(tmp, "manifest.json"), "w") as f:
+                    json.dump(manifest, f)
+                with open(os.path.join(tmp, "COMMITTED"), "w") as f:
+                    f.write("ok")
+                shutil.rmtree(final, ignore_errors=True)
+                os.rename(tmp, final)
+                self._gc()
 
         if blocking:
             write()

@@ -9,7 +9,6 @@ benchmarks/preprocessing.py.
 from __future__ import annotations
 
 import dataclasses
-import time
 
 import numpy as np
 
@@ -20,6 +19,7 @@ from repro.core.graph import Graph
 from repro.core.reformation import (BUCKET_MASKED, ClusterLayout,
                                     augment_edges, build_layout)
 from repro.core.reorder import cluster_reorder, cut_ratio
+from repro.runtime.spans import span
 
 
 @dataclasses.dataclass
@@ -28,7 +28,6 @@ class PreparedGraph:
     layout: ClusterLayout
     report: ConditionReport
     cut: float
-    prep_seconds: float
     # cluster-reorder permutation (perm[i] = original node id at sequence
     # position i - n_global); None for multi-graph batches. Tasks that
     # address nodes directly (LinkTask edge endpoints) map original ids
@@ -77,74 +76,79 @@ def prepare_node_task_ladder(g: Graph, cfg, beta_thres,
     ``block_idx``/``buckets``/``dense_buckets`` depend on the threshold).
     The shared batch arrays are aliased across rungs (treat as
     read-only)."""
-    t0 = time.perf_counter()
-    while bq > 8 and (g.n + cfg.n_global) < 4 * bq:
-        bq //= 2
-        bk //= 2
-    k_clusters = k_clusters or choose_cluster_dim(g.n, cfg.d_model, bq)
-    perm, assign = cluster_reorder(g, k_clusters, seed=seed)
-    gp = g.permuted(perm)
-    # conditions are checked on the AUGMENTED pattern the layout actually
-    # uses (self loops C1, chain C2, global-token edges C3)
-    ar, ac, s0 = augment_edges(gp, cfg.n_global, chain=True)
-    gaug = Graph(s0, ar.astype(np.int32), ac.astype(np.int32))
-    report = check_conditions(gaug, cfg.n_layers)
+    with span("repro.prep.reorder"):
+        while bq > 8 and (g.n + cfg.n_global) < 4 * bq:
+            bq //= 2
+            bk //= 2
+        k_clusters = k_clusters or choose_cluster_dim(g.n, cfg.d_model, bq)
+        perm, assign = cluster_reorder(g, k_clusters, seed=seed)
+        gp = g.permuted(perm)
+        cut = cut_ratio(gp, assign[perm])
+    with span("repro.prep.conditions"):
+        # conditions are checked on the AUGMENTED pattern the layout
+        # actually uses (self loops C1, chain C2, global-token edges C3)
+        ar, ac, s0 = augment_edges(gp, cfg.n_global, chain=True)
+        gaug = Graph(s0, ar.astype(np.int32), ac.astype(np.int32))
+        report = check_conditions(gaug, cfg.n_layers)
+    with span("repro.prep.encodings"):
+        spd = None
+        if cfg.graph_bias == "spd":
+            spd = spd_matrix(gp.with_self_loops(), cfg.max_spd)
+        pe_nodes = lap_pe(gp) if cfg.name.startswith("gt") else None
+    layouts = []
+    for bt in beta_thres:
+        with span("repro.prep.layout", beta_thre=bt):
+            layouts.append(build_layout(
+                gp, bq=bq, bk=bk, k_clusters=k_clusters, d_b=d_b,
+                beta_thre=bt, n_global=cfg.n_global, chain=True,
+                buckets=with_buckets, spd=spd, max_spd=cfg.max_spd))
 
-    spd = None
-    if cfg.graph_bias == "spd":
-        spd = spd_matrix(gp.with_self_loops(), cfg.max_spd)
-    layouts = [build_layout(
-        gp, bq=bq, bk=bk, k_clusters=k_clusters, d_b=d_b,
-        beta_thre=bt, n_global=cfg.n_global, chain=True,
-        buckets=with_buckets, spd=spd, max_spd=cfg.max_spd)
-        for bt in beta_thres]
+    with span("repro.prep.pack"):
+        S = layouts[0].seq_len
+        ng = cfg.n_global
+        feat = np.zeros((1, S, cfg.feat_dim), np.float32)
+        feat[0, ng:ng + g.n] = gp.feat
+        ind, outd = gp.degrees()
+        in_deg = np.zeros((1, S), np.int32)
+        out_deg = np.zeros((1, S), np.int32)
+        in_deg[0, ng:ng + g.n] = degree_clip(ind, cfg.max_degree)
+        out_deg[0, ng:ng + g.n] = degree_clip(outd, cfg.max_degree)
+        labels = np.full((1, S), -1, np.int32)
+        # label-less graphs (link tasks) stay masked
+        if gp.labels is not None:
+            lab = gp.labels.copy()
+            if train_mask is not None:
+                tm = train_mask[perm]
+                lab = np.where(tm, lab, -1)
+            labels[0, ng:ng + g.n] = lab
+        pe = None
+        if pe_nodes is not None:
+            pe = np.zeros((1, S, 8), np.float32)
+            pe[0, ng:ng + g.n] = pe_nodes
 
-    S = layouts[0].seq_len
-    ng = cfg.n_global
-    feat = np.zeros((1, S, cfg.feat_dim), np.float32)
-    feat[0, ng:ng + g.n] = gp.feat
-    ind, outd = gp.degrees()
-    in_deg = np.zeros((1, S), np.int32)
-    out_deg = np.zeros((1, S), np.int32)
-    in_deg[0, ng:ng + g.n] = degree_clip(ind, cfg.max_degree)
-    out_deg[0, ng:ng + g.n] = degree_clip(outd, cfg.max_degree)
-    labels = np.full((1, S), -1, np.int32)
-    if gp.labels is not None:  # label-less graphs (link tasks) stay masked
-        lab = gp.labels.copy()
-        if train_mask is not None:
-            tm = train_mask[perm]
-            lab = np.where(tm, lab, -1)
-        labels[0, ng:ng + g.n] = lab
-    pe = None
-    if cfg.name.startswith("gt"):
-        pe = np.zeros((1, S, 8), np.float32)
-        pe[0, ng:ng + g.n] = lap_pe(gp)
-    cut = cut_ratio(gp, assign[perm])
-
-    out = []
-    t_prev = t0
-    for layout in layouts:
-        batch = {
-            "feat": feat,
-            "in_deg": in_deg,
-            "out_deg": out_deg,
-            "labels": labels,
-            "block_idx": layout.block_idx[None],
-        }
-        if layout.block_idx_t is not None:
-            # transposed pattern for the dK/dV backward kernel
-            batch["block_idx_t"] = layout.block_idx_t[None]
-        if layout.buckets is not None:
-            batch["buckets"] = layout.buckets[None]
-        if pe is not None:
-            batch["lap_pe"] = pe
-        if with_dense_buckets:
-            from repro.core.dual_attention import dense_buckets_from_layout
-            batch["dense_buckets"] = dense_buckets_from_layout(layout)[None]
-        now = time.perf_counter()
-        out.append(PreparedGraph(batch, layout, report, cut, now - t_prev,
-                                 perm=perm))
-        t_prev = now
+        out = []
+        for layout in layouts:
+            batch = {
+                "feat": feat,
+                "in_deg": in_deg,
+                "out_deg": out_deg,
+                "labels": labels,
+                "block_idx": layout.block_idx[None],
+            }
+            if layout.block_idx_t is not None:
+                # transposed pattern for the dK/dV backward kernel
+                batch["block_idx_t"] = layout.block_idx_t[None]
+            if layout.buckets is not None:
+                batch["buckets"] = layout.buckets[None]
+            if pe is not None:
+                batch["lap_pe"] = pe
+            if with_dense_buckets:
+                from repro.core.dual_attention import \
+                    dense_buckets_from_layout
+                batch["dense_buckets"] = \
+                    dense_buckets_from_layout(layout)[None]
+            out.append(PreparedGraph(batch, layout, report, cut,
+                                     perm=perm))
     return out
 
 
@@ -187,7 +191,7 @@ def pad_layout_mb(prep: PreparedGraph, mb: int,
                            lay.n_buckets, lay.stats,
                            block_idx_t=block_idx_t)
     return PreparedGraph(batch, layout, prep.report, prep.cut,
-                         prep.prep_seconds, perm=prep.perm)
+                         perm=prep.perm)
 
 
 def prepare_graph_task(graphs: list[Graph], cfg, *, bq: int = 32,
@@ -220,21 +224,26 @@ def prepare_graph_task_ladder(graphs: list[Graph], cfg, beta_thres,
     exactly like :func:`prepare_node_task_ladder` does for single-graph
     tasks — probing an AutoTuner ladder costs one reorder pass plus a
     layout per (graph, rung)."""
-    t0 = time.perf_counter()
     invariant = []   # (gp, k_clusters, spd) per graph
+    pes = []         # Laplacian PE per graph (GT, graphs of 2+ nodes)
     cuts = []
     reports = []
+    gt = cfg.name.startswith("gt")
     for gr in graphs:
-        k = max(1, min(4, gr.n // (2 * bq) or 1))
-        perm, assign = cluster_reorder(gr, k, seed=seed)
-        gp = gr.permuted(perm)
-        cuts.append(cut_ratio(gp, assign[perm]))
-        ar, ac, s0 = augment_edges(gp, cfg.n_global, chain=True)
-        reports.append(check_conditions(
-            Graph(s0, ar.astype(np.int32), ac.astype(np.int32)),
-            cfg.n_layers))
-        spd = spd_matrix(gp.with_self_loops(), cfg.max_spd) \
-            if cfg.graph_bias == "spd" else None
+        with span("repro.prep.reorder"):
+            k = max(1, min(4, gr.n // (2 * bq) or 1))
+            perm, assign = cluster_reorder(gr, k, seed=seed)
+            gp = gr.permuted(perm)
+            cuts.append(cut_ratio(gp, assign[perm]))
+        with span("repro.prep.conditions"):
+            ar, ac, s0 = augment_edges(gp, cfg.n_global, chain=True)
+            reports.append(check_conditions(
+                Graph(s0, ar.astype(np.int32), ac.astype(np.int32)),
+                cfg.n_layers))
+        with span("repro.prep.encodings"):
+            spd = spd_matrix(gp.with_self_loops(), cfg.max_spd) \
+                if cfg.graph_bias == "spd" else None
+            pes.append(lap_pe(gp) if gt and gp.n > 1 else None)
         invariant.append((gp, k, spd))
     report = ConditionReport(
         all(r.c1_self_loops for r in reports),
@@ -247,56 +256,52 @@ def prepare_graph_task_ladder(graphs: list[Graph], cfg, beta_thres,
     # else (feat, degrees, labels, lap_pe) is packed ONCE and ALIASED
     # across rungs (same guarantee as prepare_node_task_ladder — the
     # elastic upload dedup relies on the shared identity)
-    per_rung = [[build_layout(
-        gp, bq=bq, bk=bk, k_clusters=k, d_b=d_b, beta_thre=bt,
-        n_global=cfg.n_global, chain=True, buckets=True, spd=spd,
-        max_spd=cfg.max_spd) for gp, k, spd in invariant]
-        for bt in beta_thres]
+    per_rung = []
+    for bt in beta_thres:
+        with span("repro.prep.layout", beta_thre=bt):
+            per_rung.append([build_layout(
+                gp, bq=bq, bk=bk, k_clusters=k, d_b=d_b, beta_thre=bt,
+                n_global=cfg.n_global, chain=True, buckets=True, spd=spd,
+                max_spd=cfg.max_spd) for gp, k, spd in invariant])
     S = max(lay.seq_len for lay in per_rung[0])  # seq is rung-invariant
     S = -(-S // max(bq, bk)) * max(bq, bk)
     gps = [gp for gp, _, _ in invariant]
-    inv_batch = _pack_graph_invariant(gps, cfg, S)
-    out = []
-    t_prev = t0
-    for layouts in per_rung:
-        p = _pack_graph_rung(gps, layouts, inv_batch, cfg, bq, bk,
-                             S, report, cut, 0.0,
-                             with_dense_buckets=with_dense_buckets)
-        now = time.perf_counter()
-        p.prep_seconds = now - t_prev  # rung 0 carries the shared prep
-        t_prev = now
-        out.append(p)
-    if seq_pad is None:
-        seq_pad = max(p.layout.seq_len for p in out)
-    if mb_pad is None:
-        mb_pad = max(p.layout.mb for p in out)
-    mt_pad = max(p.layout.mt for p in out)
-    shared: dict = {}  # keep invariant arrays aliased through the pad
-    out = [pad_graph_batch(p, seq_pad, mb_pad, mt_pad, _shared=shared)
-           for p in out]
-    out[-1].prep_seconds += time.perf_counter() - t_prev  # the pad pass
+    with span("repro.prep.pack"):
+        inv_batch = _pack_graph_invariant(gps, pes if gt else None, cfg, S)
+        out = [_pack_graph_rung(gps, layouts, inv_batch, cfg, bq, bk, S,
+                                report, cut,
+                                with_dense_buckets=with_dense_buckets)
+               for layouts in per_rung]
+    with span("repro.prep.pad"):
+        if seq_pad is None:
+            seq_pad = max(p.layout.seq_len for p in out)
+        if mb_pad is None:
+            mb_pad = max(p.layout.mb for p in out)
+        mt_pad = max(p.layout.mt for p in out)
+        shared: dict = {}  # keep invariant arrays aliased through the pad
+        out = [pad_graph_batch(p, seq_pad, mb_pad, mt_pad, _shared=shared)
+               for p in out]
     return out
 
 
-def _pack_graph_invariant(gps, cfg, S):
+def _pack_graph_invariant(gps, pes, cfg, S):
     """The rung-invariant half of a packed graph batch: features, clipped
-    degrees, global-token labels and (GT) lap-PE."""
+    degrees, global-token labels and, given ``pes`` (GT), lap-PE."""
     B = len(gps)
     ng = cfg.n_global
     feat = np.zeros((B, S, cfg.feat_dim), np.float32)
     in_deg = np.zeros((B, S), np.int32)
     out_deg = np.zeros((B, S), np.int32)
     labels = np.full((B, S), -1, np.int32)
-    pe = np.zeros((B, S, 8), np.float32) if cfg.name.startswith("gt") \
-        else None
+    pe = np.zeros((B, S, 8), np.float32) if pes is not None else None
     for i, gp in enumerate(gps):
         feat[i, ng:ng + gp.n] = gp.feat
         ind, outd = gp.degrees()
         in_deg[i, ng:ng + gp.n] = degree_clip(ind, cfg.max_degree)
         out_deg[i, ng:ng + gp.n] = degree_clip(outd, cfg.max_degree)
         labels[i, 0] = gp.labels[0]  # graph label (stored on node 0)
-        if pe is not None and gp.n > 1:
-            pe[i, ng:ng + gp.n] = lap_pe(gp)
+        if pe is not None and pes[i] is not None:
+            pe[i, ng:ng + gp.n] = pes[i]
     batch = {"feat": feat, "in_deg": in_deg, "out_deg": out_deg,
              "labels": labels}
     if pe is not None:
@@ -305,7 +310,7 @@ def _pack_graph_invariant(gps, cfg, S):
 
 
 def _pack_graph_rung(gps, layouts, inv_batch, cfg, bq, bk, S, report, cut,
-                     prep_seconds, *, with_dense_buckets: bool):
+                     *, with_dense_buckets: bool):
     """One rung's PreparedGraph: the rung-dependent pattern arrays packed
     around the shared (aliased, treat as read-only) invariant batch."""
     B = len(gps)
@@ -346,7 +351,7 @@ def _pack_graph_rung(gps, layouts, inv_batch, cfg, bq, bk, S, report, cut,
     layout = ClusterLayout(S, bq, bk, block_idx[0], buckets[0],
                            layouts[0].n_buckets, stats,
                            block_idx_t=block_idx_t[0])
-    return PreparedGraph(batch, layout, report, cut, prep_seconds)
+    return PreparedGraph(batch, layout, report, cut)
 
 
 def pad_graph_batch(prep: PreparedGraph, seq: int, mb: int,
@@ -416,5 +421,4 @@ def pad_graph_batch(prep: PreparedGraph, seq: int, mb: int,
                            lay.stats,
                            block_idx_t=batch.get("block_idx_t",
                                                  [None])[0])
-    return PreparedGraph(batch, layout, prep.report, prep.cut,
-                         prep.prep_seconds)
+    return PreparedGraph(batch, layout, prep.report, prep.cut)

@@ -67,6 +67,7 @@ from repro.kernels import ops as kernel_ops
 from repro.optim.adamw import AdamW, warmup_cosine
 from repro.parallel.axes import axis_rules
 from repro.resilience.faults import FaultPlan, Preempted
+from repro.runtime.spans import span
 from repro.tasks.base import BatchFnTask
 
 
@@ -370,7 +371,8 @@ class Trainer:
     # ------------------------------------------------------------ loop
 
     def run(self, seed: int = 0):
-        state, start = self.restore_or_init(seed)
+        with span("repro.trainer.init"):
+            state, start = self.restore_or_init(seed)
         cfg = self.cfg
         task = self.task
         if self._ir_audit_enabled():
@@ -397,96 +399,108 @@ class Trainer:
         try:
             step = start
             while step < cfg.steps:
-                if step == cfg.fail_at_step:
-                    raise RuntimeError(f"injected failure at step {step}")
-                t0 = time.perf_counter()
-                # the task owns the schedule (dual-interleave for graph
-                # tasks, always-"sparse" for streams); absolute step ->
-                # cadence survives restart
-                variant = task.variant(step, cfg.interleave_period)
-                batch = task.batches(step)
-                # fault hooks (repro.resilience): the nonfinite operand
-                # is 1.0 (bitwise identity) unless this step is armed;
-                # preemption keeps the pre-step carry so the raise lands
-                # after donation consumed it — worst-case instant
-                nf = self.faults.take("nonfinite", step)
-                scale = np.float32("nan" if nf else 1.0)
-                pre = self.faults.take("preempt", step)
-                prev = state if pre is not None else None
-                with self._mesh_ctx():
-                    state, metrics = self._steps[variant](
-                        state, batch, scale)
-                if nf is not None:
-                    self.fault_log.append(
-                        {"kind": "nonfinite", "step": step})
-                if pre is not None:
-                    # a real preemption kills the process mid-step: the
-                    # outputs never escape, and under donation the
-                    # inputs are already deleted — exactly what the
-                    # crash save's rescue fallback must survive
-                    state = prev
-                    self.fault_log.append(
-                        {"kind": "preempt", "step": step})
-                    raise Preempted(
-                        f"injected preemption at step {step}")
-                metrics = {k: float(v) for k, v in metrics.items()}
-                dt = time.perf_counter() - t0
-                if step - start >= 2:  # skip compile-dominated warmup steps
-                    prev_ema = ema
-                    ema = dt if ema is None else 0.9 * ema + 0.1 * dt
-                    if prev_ema is not None and \
-                            dt > cfg.straggler_factor * prev_ema:
-                        self.stragglers.append(
-                            StragglerReport(step, dt, prev_ema))
-                rec = {"step": step + 1, **metrics, "seconds": dt,
-                       "variant": variant, "dense": variant == "dense",
-                       **task.log_extras()}
-                self.history.append(rec)
-                if rescue_on and (step + 1) % cfg.rescue_every == 0:
-                    # undonated host copy: the crash save below must not
-                    # touch buffers the next step call donates away
-                    self._rescue = (step + 1, jax.device_get(state))
-                if cfg.elastic_every > 0:
-                    # compile-dominated warmup steps would poison the LDR
-                    # denominator (the straggler EMA skips them too);
-                    # non-finite losses (guard-skipped steps) would
-                    # poison the mean
-                    if step - start >= 2 and np.isfinite(metrics["loss"]):
-                        epoch_losses.append(metrics["loss"])
-                        epoch_seconds += dt
-                    if (step + 1) % cfg.elastic_every == 0:
-                        if epoch_losses:
-                            task.on_epoch(float(np.mean(epoch_losses)),
-                                          epoch_seconds, step=step + 1)
+                with span("repro.trainer.step", step=step) as sp:
+                    if step == cfg.fail_at_step:
+                        raise RuntimeError(
+                            f"injected failure at step {step}")
+                    t0 = time.perf_counter()
+                    # the task owns the schedule (dual-interleave for graph
+                    # tasks, always-"sparse" for streams); absolute step ->
+                    # cadence survives restart
+                    variant = task.variant(step, cfg.interleave_period)
+                    sp.attrs["variant"] = variant
+                    with span("repro.task.batches"):
+                        batch = task.batches(step)
+                    # fault hooks (repro.resilience): the nonfinite
+                    # operand is 1.0 (bitwise identity) unless this step is
+                    # armed; preemption keeps the pre-step carry so the
+                    # raise lands after donation consumed it
+                    nf = self.faults.take("nonfinite", step)
+                    scale = np.float32("nan" if nf else 1.0)
+                    pre = self.faults.take("preempt", step)
+                    prev = state if pre is not None else None
+                    with self._mesh_ctx(), span("repro.trainer.dispatch"):
+                        state, metrics = self._steps[variant](
+                            state, batch, scale)
+                    if nf is not None:
+                        self.fault_log.append(
+                            {"kind": "nonfinite", "step": step})
+                    if pre is not None:
+                        # a real preemption kills the process mid-step:
+                        # the outputs never escape, and under donation the
+                        # inputs are already deleted — exactly what the
+                        # crash save's rescue fallback must survive
+                        state = prev
+                        self.fault_log.append(
+                            {"kind": "preempt", "step": step})
+                        raise Preempted(
+                            f"injected preemption at step {step}")
+                    # the host blocks here until the device ends the step
+                    with span("repro.trainer.wait") as wait:
+                        metrics = {k: float(v) for k, v in metrics.items()}
+                    dt = time.perf_counter() - t0
+                    if step - start >= 2:  # skip compile-dominated warmup
+                        prev_ema = ema
+                        ema = dt if ema is None else 0.9 * ema + 0.1 * dt
+                        if prev_ema is not None and \
+                                dt > cfg.straggler_factor * prev_ema:
+                            self.stragglers.append(
+                                StragglerReport(step, dt, prev_ema))
+                    rec = {"step": step + 1, **metrics, "seconds": dt,
+                           "wait_s": wait.seconds, "variant": variant,
+                           "dense": variant == "dense", **task.log_extras()}
+                    self.history.append(rec)
+                    if rescue_on and (step + 1) % cfg.rescue_every == 0:
+                        # undonated host copy: the crash save below must
+                        # not touch buffers the next step call donates away
+                        with span("repro.trainer.rescue"):
+                            self._rescue = (step + 1, jax.device_get(state))
+                    if cfg.elastic_every > 0:
+                        # compile-dominated warmup steps would poison the
+                        # LDR denominator (the straggler EMA skips them
+                        # too); non-finite losses (guard-skipped steps)
+                        # would poison the mean
+                        if step - start >= 2 and \
+                                np.isfinite(metrics["loss"]):
+                            epoch_losses.append(metrics["loss"])
+                            epoch_seconds += dt
+                        if (step + 1) % cfg.elastic_every == 0:
+                            if epoch_losses:
+                                with span("repro.task.on_epoch"):
+                                    task.on_epoch(
+                                        float(np.mean(epoch_losses)),
+                                        epoch_seconds, step=step + 1)
+                            epoch_losses, epoch_seconds = [], 0.0
+                    if cfg.retune_every > 0 and \
+                            (step + 1) % cfg.retune_every == 0:
+                        # winner-table refresh (TrainerConfig.retune_every):
+                        # warn-and-fallback on any load problem, never
+                        # raises, never retraces the live step executables
+                        from repro.tune import runtime as tune_runtime
+                        with span("repro.tune.refresh"):
+                            tune_runtime.refresh(cfg.tune_table or None)
+                    # the final blocking save below covers step == cfg.steps
+                    if (step + 1) % cfg.ckpt_every == 0 and \
+                            step + 1 != cfg.steps:
+                        self.ckpt.save(step + 1, state,
+                                       extra=self._ckpt_extra())
+                        self._maybe_corrupt(step + 1)
+                    if self._preempted:
+                        self.ckpt.save(step + 1, state, blocking=True,
+                                       extra=self._ckpt_extra())
+                        return state, "preempted"
+                    # escalation: the in-step guard already skipped each
+                    # bad update; a persistent streak means the carry itself
+                    # may be poisoned (e.g. optimizer moments) — roll back
+                    # to the newest verified checkpoint outside the streak
+                    if cfg.max_bad_steps > 0 and \
+                            metrics["bad_steps"] >= cfg.max_bad_steps:
+                        with span("repro.trainer.rollback"):
+                            state, step = self._rollback(step + 1, seed)
+                        ema = None
                         epoch_losses, epoch_seconds = [], 0.0
-                if cfg.retune_every > 0 and \
-                        (step + 1) % cfg.retune_every == 0:
-                    # winner-table refresh (see TrainerConfig.retune_every):
-                    # warn-and-fallback on any load problem, never raises,
-                    # never retraces the live step executables
-                    from repro.tune import runtime as tune_runtime
-                    tune_runtime.refresh(cfg.tune_table or None)
-                # the final blocking save below covers step == cfg.steps
-                if (step + 1) % cfg.ckpt_every == 0 and \
-                        step + 1 != cfg.steps:
-                    self.ckpt.save(step + 1, state,
-                                   extra=self._ckpt_extra())
-                    self._maybe_corrupt(step + 1)
-                if self._preempted:
-                    self.ckpt.save(step + 1, state, blocking=True,
-                                   extra=self._ckpt_extra())
-                    return state, "preempted"
-                # escalation: the in-step guard already skipped each bad
-                # update; a persistent streak means the carry itself may
-                # be poisoned (e.g. optimizer moments) — roll back to
-                # the newest verified checkpoint outside the streak
-                if cfg.max_bad_steps > 0 and \
-                        metrics["bad_steps"] >= cfg.max_bad_steps:
-                    state, step = self._rollback(step + 1, seed)
-                    ema = None
-                    epoch_losses, epoch_seconds = [], 0.0
-                    continue
-                step += 1
+                        continue
+                    step += 1
             self.ckpt.save(cfg.steps, state, blocking=True,
                            extra=self._ckpt_extra())
             self._maybe_corrupt(cfg.steps)

@@ -50,6 +50,7 @@ class ElasticTask(Task):
     active rung."""
 
     name = "elastic"
+    prep_seconds: float  # the subclass's ``repro.task.prep`` span
 
     def _init_ladder(self, beta_g: float, delta: int) -> list[float]:
         """Create the tuner; returns the deduped rung thresholds to
@@ -88,8 +89,6 @@ class ElasticTask(Task):
                     raise AssertionError(
                         f"rung/mini-batch shape drift: {got} != {shapes}")
         self.mb_cap = first.layout.mb
-        self.prep_seconds = sum(p.prep_seconds
-                                for ps in self._preps.values() for p in ps)
 
     # ------------------------------------------------------------ state
 

@@ -21,6 +21,7 @@ import numpy as np
 
 from repro.data.graph_pipeline import (pad_graph_batch,
                                        prepare_graph_task_ladder)
+from repro.runtime.spans import span
 from repro.tasks.elastic import ElasticTask
 
 
@@ -51,9 +52,11 @@ class GraphLevelTask(ElasticTask):
         # one ladder of preps per mini-batch, then one shape budget over
         # everything (rungs AND mini-batches): ladder moves and batch
         # cycling both swap contents only
-        per_batch = [prepare_graph_task_ladder(
-            gs, cfg, betas, bq=bq, bk=bk, d_b=d_b,
-            with_dense_buckets=True, seed=seed) for gs in splits]
+        with span("repro.task.prep") as prep:
+            per_batch = [prepare_graph_task_ladder(
+                gs, cfg, betas, bq=bq, bk=bk, d_b=d_b,
+                with_dense_buckets=True, seed=seed) for gs in splits]
+        self.prep_seconds = prep.seconds
         seq_cap = max(p.layout.seq_len for ps in per_batch for p in ps)
         mb_cap = max(p.layout.mb for ps in per_batch for p in ps)
         mt_cap = max(p.layout.mt for ps in per_batch for p in ps)

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.data.graph_pipeline import pad_layout_mb, prepare_node_task_ladder
+from repro.runtime.spans import span
 from repro.tasks.elastic import ElasticTask
 
 
@@ -45,16 +46,20 @@ class NodeTask(ElasticTask):
         self.cfg = cfg
         self.g = g
         betas = self._init_ladder(g.sparsity, delta)
-        preps = dict(zip(betas, prepare_node_task_ladder(
-            g, cfg, betas, bq=bq, bk=bk, d_b=d_b, train_mask=train_mask,
-            with_dense_buckets=True, seed=seed)))
+        with span("repro.task.prep") as prep:
+            preps = dict(zip(betas, prepare_node_task_ladder(
+                g, cfg, betas, bq=bq, bk=bk, d_b=d_b,
+                train_mask=train_mask, with_dense_buckets=True,
+                seed=seed)))
+        self.prep_seconds = prep.seconds
         seqs = {p.layout.seq_len for p in preps.values()}
         if len(seqs) != 1:  # deterministic prep => can't happen; be loud
             raise AssertionError(f"re-layout changed seq_len: {seqs}")
         mb_cap = max(p.layout.mb for p in preps.values())
         mt_cap = max(p.layout.mt for p in preps.values())
-        self._set_rungs({bt: [pad_layout_mb(p, mb_cap, mt_cap)]
-                         for bt, p in preps.items()})
+        with span("repro.prep.pad"):
+            self._set_rungs({bt: [pad_layout_mb(p, mb_cap, mt_cap)]
+                             for bt, p in preps.items()})
         # held-out labels for eval: the permuted full label vector, with
         # train positions masked out when a train_mask was given
         ng = cfg.n_global

@@ -1,6 +1,7 @@
 """Runtime: trainer fault tolerance, checkpointing, optimizer, data."""
 
 import shutil
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -178,3 +179,108 @@ def test_checkpoint_extra_manifest_roundtrip(tmp_path):
     ck.save(2, {"x": jnp.ones(2)}, blocking=True)
     assert ck.load_extra(1) == extra
     assert ck.load_extra(2) is None
+
+
+# ---------------------------------------------------------------- spans
+
+def _spans_node_run(tmp_path, steps=6):
+    from repro.core.graph import sbm_graph
+    from repro.runtime import spans
+    from repro.tasks import NodeTask
+
+    cfg = get_smoke_config("graphormer_slim")
+    g = sbm_graph(96, 4, p_in=0.05, p_out=0.003, feat_dim=cfg.feat_dim,
+                  n_classes=cfg.n_classes, seed=0)
+    tc = TrainerConfig(steps=steps, ckpt_every=3,
+                       ckpt_dir=str(tmp_path / "ck"), lr=1e-3, warmup=2,
+                       interleave_period=2)
+    with spans.recording() as rec:
+        task = NodeTask(g, cfg, bq=16, bk=16, d_b=8)
+        tr = Trainer(build(cfg), tc, task=task)
+        tr.run()
+    return rec, task, tr
+
+
+def test_trainer_spans_steps_children_and_compiles(tmp_path):
+    from repro.runtime import spans
+
+    rec, task, tr = _spans_node_run(tmp_path)
+    by_id = {s["id"]: s for s in rec.spans}
+    steps = [s for s in rec.spans if s["name"] == "repro.trainer.step"]
+    assert [s["attrs"]["step"] for s in steps] == list(range(6))
+    assert [s["attrs"]["variant"] for s in steps] == \
+        ["dense", "sparse"] * 3
+    kids = {s["id"]: [] for s in steps}
+    for s in rec.spans:
+        if s["parent"] in kids:
+            kids[s["parent"]].append(s["name"])
+    for s in steps:
+        k = kids[s["id"]]
+        for want in ("repro.task.batches", "repro.trainer.dispatch",
+                     "repro.trainer.wait", "repro.trainer.rescue"):
+            assert k.count(want) == 1, (s["attrs"], k)
+    # saves after steps 3 (async: the write runs on its own thread) and
+    # 6 (the final blocking save, outside every step)
+    saves = [s for s in rec.spans if s["name"] == "repro.ckpt.save"]
+    assert [by_id[s["parent"]]["attrs"]["step"]
+            for s in saves if s["parent"] is not None] == [2]
+    writes = [s for s in rec.spans if s["name"] == "repro.ckpt.write"]
+    assert len(writes) == 2
+    assert any(w["thread"] != threading.current_thread().name and
+               w["parent"] is None for w in writes)
+    init = [s for s in rec.spans if s["name"] == "repro.trainer.init"]
+    assert len(init) == 1 and init[0]["parent"] is None
+    # each variant compiles at its first call and never again
+    comp = spans.compiles_by_step(rec)
+    compiled = sorted(k for k, v in comp.items() if k is not None and
+                      any(v.get(n, 0) > 0 for n in spans.COMPILE_SECONDS))
+    assert compiled == [0, 1], comp
+    for h in tr.history:
+        assert 0 < h["wait_s"] <= h["seconds"]
+    wait = [s for s in rec.spans if s["name"] == "repro.trainer.wait"]
+    assert [h["wait_s"] for h in tr.history] == pytest.approx(
+        [(s["end_ns"] - s["start_ns"]) * 1e-9 for s in wait])
+
+
+def test_task_prep_seconds_is_its_span(tmp_path):
+    rec, task, _ = _spans_node_run(tmp_path, steps=1)
+    prep = [s for s in rec.spans if s["name"] == "repro.task.prep"]
+    assert len(prep) == 1
+    assert task.prep_seconds == pytest.approx(
+        (prep[0]["end_ns"] - prep[0]["start_ns"]) * 1e-9)
+    kids = [s["name"] for s in rec.spans if s["parent"] == prep[0]["id"]]
+    assert sorted(set(kids)) == ["repro.prep.conditions",
+                                 "repro.prep.encodings",
+                                 "repro.prep.layout", "repro.prep.pack",
+                                 "repro.prep.reorder"]
+    # one layout span per ladder rung, each naming its threshold
+    rungs = [s["attrs"]["beta_thre"] for s in rec.spans
+             if s["name"] == "repro.prep.layout"]
+    assert rungs == list(dict.fromkeys(task.tuner.ladder))
+
+
+def test_graph_level_prep_seconds_is_its_span():
+    """GraphLevelTask: one ``repro.task.prep`` over every mini-batch's
+    ladder, the per-graph work inside it once per graph and the pad pass
+    once per mini-batch."""
+    from repro.runtime import spans
+    from repro.tasks import GraphLevelTask, synthetic_graph_level_dataset
+
+    cfg = get_smoke_config("gt")
+    graphs = synthetic_graph_level_dataset(4, cfg, seed=1, n_lo=20,
+                                           n_hi=40)
+    with spans.recording() as rec:
+        task = GraphLevelTask(graphs, cfg, batch_graphs=2, delta=2)
+    prep = [s for s in rec.spans if s["name"] == "repro.task.prep"]
+    assert len(prep) == 1
+    assert task.prep_seconds == pytest.approx(
+        (prep[0]["end_ns"] - prep[0]["start_ns"]) * 1e-9)
+    n = {}
+    for s in rec.spans:
+        if s["parent"] == prep[0]["id"]:
+            n[s["name"]] = n.get(s["name"], 0) + 1
+    rungs = len(dict.fromkeys(task.tuner.ladder))
+    assert n == {"repro.prep.reorder": 4, "repro.prep.conditions": 4,
+                 "repro.prep.encodings": 4, "repro.prep.layout": 2 * rungs,
+                 "repro.prep.pack": 2, "repro.prep.pad": 2}
+    assert task.batches(0)["lap_pe"].shape[-1] == 8
