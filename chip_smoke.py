@@ -124,8 +124,10 @@ def _check_history(trainer, label: str):
 def _layout_line(trainer, label: str):
     lay = trainer.task.layout
     b = trainer.task.batches(0)
-    idx_bytes = b["block_idx"].size * 4
-    idxt_bytes = b["block_idx_t"].size * 4
+    # each kernel call prefetches its stream (a word a slot of its layout)
+    # and block_idx (a word a slot)
+    idx_bytes = 2 * b["block_idx"].size * 4
+    idxt_bytes = (b["block_idx_t"].size // 2 + b["block_idx"].size) * 4
     print(f"[{label}] nodes={trainer.task.g.n} S={lay.seq_len} "
           f"bq={lay.bq} bk={lay.bk} nq={lay.nq} mb={trainer.task.mb_cap} "
           f"prefetch_bytes fwd/dq={idx_bytes} dkv={idxt_bytes} "

@@ -61,7 +61,7 @@ def sharded_attention_report(p: int = 4, *, seq: int = 1024, heads: int = 8,
     from repro.analysis.ir.dtype_flow import dtype_report
     from repro.core.reformation import lm_local_global_layout
     # the auditor needs the kernel's grid contract, not its dispatch.  # repro-lint: disable=REP002
-    from repro.kernels.cluster_attention import grid_triple
+    from repro.kernels.cluster_attention import fwd_stream, grid_triple
     from repro.kernels.ops import LANE
     from repro.parallel.cluster_parallel import (cluster_a2a_budget,
                                                  sharded_cluster_attention)
@@ -92,14 +92,14 @@ def sharded_attention_report(p: int = 4, *, seq: int = 1024, heads: int = 8,
     # the forward kernel triple exactly as the per-device launch builds
     # it: local head chunk, full (post-a2a) sequence, lane-padded Dh
     nq, mb = lay.block_idx.shape
+    idx, n = fwd_stream(jnp.asarray(lay.block_idx)[None], interpret=True)
     triple = grid_triple(1, seq, heads // p, heads // p,
-                         d_head + (-d_head % LANE), nq, mb, bk=bq,
+                         d_head + (-d_head % LANE), nq, mb, int(n), bk=bq,
                          per_graph=True, return_residuals=True)
-    idx = np.broadcast_to(np.asarray(lay.block_idx, np.int32)[None],
-                          (1, nq, mb)).reshape(-1)  # flat prefetch stream
+    idx = (np.asarray(idx), np.asarray(lay.block_idx, np.int32).reshape(-1))
     grid_findings = pallas_check.audit_grid(
         triple["grid"], triple["in_specs"], triple["out_specs"],
-        triple["in_shapes"], triple["out_shapes"], scalar_prefetch=(idx,),
+        triple["in_shapes"], triple["out_shapes"], scalar_prefetch=idx,
         label=label)
 
     dt = dtype_report(jaxpr, label=label)

@@ -62,7 +62,7 @@ def graph_defs(cfg):
     return defs
 
 
-def _graph_attn(p, cfg, h, batch, dense: bool, bias_table):
+def _graph_attn(p, cfg, h, batch, dense: bool, bias_table, streams=None):
     """Sparse steps go through the kernel dispatch layer (kernels/ops.py):
     oracle on CPU, Pallas cluster kernel on TPU / under REPRO_FORCE_PALLAS.
     Under a model-axis mesh the sparse path composes with the Ulysses a2a
@@ -81,7 +81,7 @@ def _graph_attn(p, cfg, h, batch, dense: bool, bias_table):
         bq_ = h.shape[1] // bi.shape[1]
         bk_ = bu.shape[-1] if bu is not None else bq_
         attn_fn = lambda a, b, c: kops.cluster_attention(
-            a, b, c, bi, bu, bias_table, bit, causal=False)
+            a, b, c, bi, bu, bias_table, bit, causal=False, streams=streams)
 
     ctx = pax.current()
     if ctx is not None:
@@ -126,10 +126,21 @@ def graph_forward(p, cfg, batch, dense: bool):
         h = jnp.where((pos < cfg.n_global)[None, :, None], gseq, h)
     h = pax.logical(h, "batch", "seq_outer", "embed")
     bias_table = p.get("bias_table")
+    streams = None
+    if not dense and pax.current() is None:
+        # the kernels' compacted streams, once for every layer (outside
+        # the rematerialized body, so the backward does not rebuild
+        # them); the sharded path builds its own per device
+        from repro.kernels import ops as kops
+        bi, bu = batch["block_idx"], batch.get("buckets")
+        streams = kops.cluster_streams(
+            bi, batch.get("block_idx_t"), seq_len=h.shape[1],
+            bk=bu.shape[-1] if bu is not None else h.shape[1] // bi.shape[-2])
 
     def body(h, pp):
         a = L.rmsnorm(pp["attn_norm"], h, cfg.norm_eps)
-        h = h + _graph_attn(pp["attn"], cfg, a, batch, dense, bias_table)
+        h = h + _graph_attn(pp["attn"], cfg, a, batch, dense, bias_table,
+                            streams)
         m = L.rmsnorm(pp["mlp_norm"], h, cfg.norm_eps)
         h = h + L.mlp(pp["mlp"], m)
         return pax.logical(h, "batch", "seq_outer", "embed"), None

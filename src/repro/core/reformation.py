@@ -98,6 +98,23 @@ def transpose_block_idx(block_idx: np.ndarray, nk: int) -> np.ndarray:
     return out
 
 
+def grid_steps(block_idx: np.ndarray,
+               block_idx_t: np.ndarray | None = None) -> tuple[int, int]:
+    """Grid steps per (graph, head) that the cluster kernels launch on
+    this layout: the entry counts of their compacted streams
+    (``kernels/cluster_attention.fwd_stream`` / ``dkv_stream``) — each
+    row's live slots, and one dead step for a row with none — the
+    largest over the leading graph dims. ``(n, n_t)``: the forward and dQ calls walk
+    ``block_idx``'s q-rows, the dK/dV call ``block_idx_t``'s k-blocks
+    (``n_t`` is 0 without a transposed layout)."""
+    def steps(live):
+        return int(np.maximum(live.sum(-1), 1).sum(-1).max())
+
+    n_t = 0 if block_idx_t is None else steps(
+        np.asarray(block_idx_t)[..., 0] >= 0)
+    return steps(np.asarray(block_idx) >= 0), n_t
+
+
 def augment_edges(g: Graph, n_global: int, chain: bool):
     """Position-space edge list with global tokens prepended, self loops and
     the sequential chain added (constructive C1/C2/C3)."""

@@ -17,9 +17,9 @@ from repro.core.conditions import ConditionReport, check_conditions
 from repro.core.encodings import degree_clip, lap_pe, spd_matrix
 from repro.core.graph import Graph
 from repro.core.reformation import (BUCKET_MASKED, ClusterLayout,
-                                    augment_edges, build_layout)
+                                    augment_edges, build_layout, grid_steps)
 from repro.core.reorder import cluster_reorder, cut_ratio
-from repro.runtime.spans import span
+from repro.runtime.spans import count, span
 
 
 @dataclasses.dataclass
@@ -62,6 +62,25 @@ def prepare_node_task(g: Graph, cfg, *, beta_thre: float | None = None,
     return prep
 
 
+def count_live_slots(layouts) -> None:
+    """Record one ladder rung's ``layout.live_slots`` and
+    ``layout.live_slots_t``: the grid steps per (graph, head) of the
+    cluster kernels' forward / dQ and dK/dV calls on the rung's layouts,
+    packed into one batch (:func:`repro.core.reformation.grid_steps`; a
+    q-row or k-block that a graph lacks but the batch has is one dead
+    step)."""
+    nq = max(lay.nq for lay in layouts)
+    nk = max(lay.seq_len // lay.bk for lay in layouts)
+    n = n_t = 0
+    for lay in layouts:
+        a, b = grid_steps(lay.block_idx, lay.block_idx_t)
+        n = max(n, a + nq - lay.nq)
+        if lay.block_idx_t is not None:
+            n_t = max(n_t, b + nk - lay.block_idx_t.shape[0])
+    count("layout.live_slots", n)
+    count("layout.live_slots_t", n_t)
+
+
 def prepare_node_task_ladder(g: Graph, cfg, beta_thres,
                              *, bq: int = 128, bk: int = 128,
                              d_b: int = 16, k_clusters: int | None = None,
@@ -102,6 +121,7 @@ def prepare_node_task_ladder(g: Graph, cfg, beta_thres,
                 gp, bq=bq, bk=bk, k_clusters=k_clusters, d_b=d_b,
                 beta_thre=bt, n_global=cfg.n_global, chain=True,
                 buckets=with_buckets, spd=spd, max_spd=cfg.max_spd))
+            count_live_slots(layouts[-1:])
 
     with span("repro.prep.pack"):
         S = layouts[0].seq_len
@@ -263,6 +283,7 @@ def prepare_graph_task_ladder(graphs: list[Graph], cfg, beta_thres,
                 gp, bq=bq, bk=bk, k_clusters=k, d_b=d_b, beta_thre=bt,
                 n_global=cfg.n_global, chain=True, buckets=True, spd=spd,
                 max_spd=cfg.max_spd) for gp, k, spd in invariant])
+            count_live_slots(per_rung[-1])
     S = max(lay.seq_len for lay in per_rung[0])  # seq is rung-invariant
     S = -(-S // max(bq, bk)) * max(bq, bk)
     gps = [gp for gp, _, _ in invariant]
@@ -278,6 +299,7 @@ def prepare_graph_task_ladder(graphs: list[Graph], cfg, beta_thres,
         if mb_pad is None:
             mb_pad = max(p.layout.mb for p in out)
         mt_pad = max(p.layout.mt for p in out)
+        count("layout.rect_slots", seq_pad // bq * mb_pad)
         shared: dict = {}  # keep invariant arrays aliased through the pad
         out = [pad_graph_batch(p, seq_pad, mb_pad, mt_pad, _shared=shared)
                for p in out]

@@ -75,6 +75,15 @@ derives one in-trace at the dense ``mt = nq`` bound — which requires
 duplicate-free rows (no q-row listing the same k-block twice; layout
 builders guarantee this, concrete violations warn-and-fall-back, and a
 *traced* custom layout with duplicates must thread ``block_idx_t``).
+
+The kernels never walk that rectangle: they compact both layouts in-trace
+to streams of their live slots (``kernels/cluster_attention.fwd_stream``,
+``dkv_stream``) and take the live count as a dynamic grid bound, so a
+call's grid steps follow the live count of the layout in hand. Both
+layouts are ``-1`` *padded*: each row's live slots come first, as every
+layout builder writes them (a concrete layout with a gap falls back to
+the oracle with a warning; a traced one must not have one). Every q-row,
+k-block and slot count must stay under ``FIELD_MAX`` (2**13).
 """
 
 from __future__ import annotations
@@ -95,10 +104,12 @@ from repro.kernels import ssd as _ssd
 from repro.kernels.policy import F32
 
 # re-exported for the autotuner: the forward launch contract lives in ONE
-# place (kernels/cluster_attention.grid_triple) and the dispatch layer is
-# the kernels package's public surface — REP002 keeps everything outside
-# repro/kernels/ off the kernel modules themselves
+# place (kernels/cluster_attention.grid_triple, fed by the compacted
+# stream of fwd_stream) and the dispatch layer is the kernels package's
+# public surface — REP002 keeps everything outside repro/kernels/ off the
+# kernel modules themselves
 grid_triple = _ca.grid_triple
+fwd_stream = _ca.fwd_stream
 
 MODES = ("auto", "ref", "interpret", "compiled")
 OPS = ("flash_attention", "cluster_attention", "ssd", "paged_attention")
@@ -328,6 +339,10 @@ def _cluster_illegal(q, k, v, block_idx, buckets, causal, mode, want_bq,
     if bq % SUBLANE or bk % SUBLANE:
         return f"block shape ({bq}, {bk}) is not sublane-aligned " \
                f"(multiples of {SUBLANE})"
+    widths = (nq, S // bk, block_idx.shape[-1])
+    if max(widths) >= _ca.FIELD_MAX:
+        return f"layout rows and slots {widths} overflow the kernels' " \
+               f"stream fields (< {_ca.FIELD_MAX})"
     if causal and buckets is not None:
         return "the bucketed kernel variant has no causal mask"
     if buckets is not None and buckets.ndim != block_idx.ndim + 2:
@@ -336,6 +351,18 @@ def _cluster_illegal(q, k, v, block_idx, buckets, causal, mode, want_bq,
     reason = _nonfloat(q, k, v)
     if reason:
         return reason
+    for name, arr in (("block_idx", block_idx), ("block_idx_t", None if
+                      block_idx_t is None else block_idx_t[..., 0])):
+        if arr is None or isinstance(arr, jax.core.Tracer):
+            continue
+        # the kernels take entry j of a row as its slot j: live slots
+        # first, -1 padding after (every layout builder's form). Concrete
+        # numpy only, as below.
+        live = np.asarray(arr) >= 0
+        # repro-lint: disable=REP004
+        if bool((live[..., 1:] & ~live[..., :-1]).any()):
+            return f"{name} has a live slot after a -1 in its row: the " \
+                   f"kernels need each row's live slots first"
     if block_idx_t is None and not isinstance(block_idx, jax.core.Tracer):
         # the in-trace derived transposed layout stores one visitor per
         # (q-row, k-block) — a row listing the same k-block twice cannot
@@ -401,8 +428,6 @@ def _grid_race_reason(q, k, block_idx, buckets, bias_table,
     bk = buckets.shape[-1] if buckets is not None else bq
     arr = np.asarray(block_idx, np.int32)
     per_graph = arr.ndim == 3
-    if not per_graph:
-        arr = np.broadcast_to(arr[None], (B, nq, mb))
     n_buckets = None
     if buckets is not None:
         n_buckets = bias_table.shape[1] + (1 if fuse_bias else 0)
@@ -410,13 +435,17 @@ def _grid_race_reason(q, k, block_idx, buckets, bias_table,
            hash(arr.tobytes()))
     if key in _GRID_AUDITED:
         return None
-    triple = grid_triple(B, S, H, KV, Dh + (-Dh % LANE), nq, mb,
+    with jax.core.eval_context():          # concrete inside any trace
+        stream, n = fwd_stream(jnp.asarray(arr if per_graph else arr[None]),
+                               interpret=True)
+    triple = grid_triple(B, S, H, KV, Dh + (-Dh % LANE), nq, mb, int(n),
                          bk=bk, per_graph=per_graph,
                          n_buckets=n_buckets, return_residuals=True)
     findings = pallas_check.audit_grid(
         triple["grid"], triple["in_specs"], triple["out_specs"],
         triple["in_shapes"], triple["out_shapes"],
-        scalar_prefetch=(arr.reshape(-1),), label="cluster_attention")
+        scalar_prefetch=(np.asarray(stream), arr.reshape(-1)),
+        label="cluster_attention")
     bad = _ir_errors(findings)
     if bad:
         return f"pallas grid audit: {bad[0].message}"
@@ -440,9 +469,25 @@ def _cluster_ref(q, k, v, block_idx, buckets, bias_table, *, causal,
                                     row_chunk=row_chunk)
 
 
+def cluster_streams(block_idx, block_idx_t, *, seq_len: int, bk: int):
+    """The compacted streams of one layout for the kernel path
+    (``kernels/cluster_attention_bwd.build_streams``), or None where
+    :func:`cluster_attention` resolves to the reference. A model that
+    runs every layer on one layout builds them once, outside its layer
+    loop, and passes them to each call: built inside a rematerialized
+    layer, the backward would rebuild them per layer. Calls that fall
+    back to the reference ignore them."""
+    mode = resolve_mode("cluster_attention")
+    if mode == "ref":
+        return None
+    _require_tpu("cluster_attention", mode)
+    return _cab.build_streams(block_idx, block_idx_t, seq_len // bk,
+                              interpret=mode == "interpret")
+
+
 def cluster_attention(q, k, v, block_idx, buckets=None, bias_table=None,
                       block_idx_t=None, *, causal=False, row_chunk=None,
-                      bq=None, bk=None):
+                      bq=None, bk=None, streams=None):
     """Cluster-sparse attention over a reformation layout — the production
     ``attn_fn`` of ``parallel/cluster_parallel.py`` (shape contract in the
     module docstring). ``bq``/``bk`` are only needed when they cannot be
@@ -456,7 +501,9 @@ def cluster_attention(q, k, v, block_idx, buckets=None, bias_table=None,
     FlashAttention-style recomputation — kernels/cluster_attention_bwd);
     ``block_idx_t`` is the transposed layout its dK/dV kernel consumes
     (derived in-trace at the dense bound when omitted; the ref path never
-    needs it). Per-graph (3-D) layouts run as ONE batched pallas_call."""
+    needs it). Per-graph (3-D) layouts run as ONE batched pallas_call.
+    ``streams`` (:func:`cluster_streams` of this layout) saves the kernel
+    path building them inside the call."""
     mode = resolve_mode("cluster_attention")
     _require_tpu("cluster_attention", mode)
     sched = resolve_schedule("cluster_attention", seq_len=q.shape[1],
@@ -496,7 +543,8 @@ def cluster_attention(q, k, v, block_idx, buckets=None, bias_table=None,
     return unpad(_cab.cluster_attention_vjp(
         q, k, v, block_idx, buckets, bias_table, block_idx_t,
         causal=causal, interpret=interpret,
-        hoist_scale=sched.hoist_scale, fuse_bias=fuse_bias))
+        hoist_scale=sched.hoist_scale, fuse_bias=fuse_bias,
+        streams=streams))
 
 
 # --------------------------------------------------------------- paged

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.data.graph_pipeline import pad_layout_mb, prepare_node_task_ladder
-from repro.runtime.spans import span
+from repro.runtime.spans import count, span
 from repro.tasks.elastic import ElasticTask
 
 
@@ -60,6 +60,7 @@ class NodeTask(ElasticTask):
         with span("repro.prep.pad"):
             self._set_rungs({bt: [pad_layout_mb(p, mb_cap, mt_cap)]
                              for bt, p in preps.items()})
+            count("layout.rect_slots", self.layout.nq * mb_cap)
         # held-out labels for eval: the permuted full label vector, with
         # train positions masked out when a train_mask was given
         ng = cfg.n_global

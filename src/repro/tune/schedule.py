@@ -193,6 +193,9 @@ def enumerate_schedules(op: str, case: dict) -> list[Schedule]:
         # block shape is the layout's; candidates vary the rewrites and
         # the oracle row_chunk. fuse_bias changes the bias operand width
         # (sentinel column), so each flag combo gets its own grid audit.
+        import jax
+        import jax.numpy as jnp
+
         from repro.kernels import ops as kops
 
         lay = case["lay"]
@@ -201,19 +204,21 @@ def enumerate_schedules(op: str, case: dict) -> list[Schedule]:
         S = case["seq_len"]
         nq, mb = lay.block_idx.shape[-2:]
         bk = lay.buckets.shape[-1] if lay.buckets is not None else S // nq
-        arr = np.broadcast_to(np.asarray(lay.block_idx, np.int32)
-                              .reshape((-1, nq, mb))[:1],
-                              (B, nq, mb)).reshape(-1)  # flat stream
+        bi = np.asarray(lay.block_idx, np.int32).reshape((-1, nq, mb))[:1]
+        bi = np.broadcast_to(bi, (B, nq, mb))
+        with jax.core.eval_context():
+            stream, n = kops.fwd_stream(jnp.asarray(bi), interpret=True)
+        prefetch = (np.asarray(stream), bi.reshape(-1))
         nb = case.get("n_buckets", getattr(lay, "n_buckets", None))
         for fuse in (False, True):
             if fuse and nb is None:
                 continue
             triple = kops.grid_triple(
-                B, S, H, KV, Dh + (-Dh % _LANE), nq, mb, bk=bk,
+                B, S, H, KV, Dh + (-Dh % _LANE), nq, mb, int(n), bk=bk,
                 per_graph=True,
                 n_buckets=(nb + 1 if fuse else nb) if nb else None,
                 return_residuals=True)
-            if _audit_triple(triple, scalar_prefetch=(arr,),
+            if _audit_triple(triple, scalar_prefetch=prefetch,
                              label=f"tune:cluster:fuse={fuse}"):
                 continue
             for hoist in (False, True):

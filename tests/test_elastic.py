@@ -15,6 +15,7 @@ from repro.ckpt.checkpoint import Checkpointer
 from repro.configs import get_smoke_config
 from repro.core.dual_attention import use_dense_step
 from repro.core.graph import sbm_graph
+from repro.core.reformation import grid_steps
 from repro.models import build
 from repro.parallel.cluster_parallel import can_shard_cluster
 from repro.runtime.elastic import ElasticGraphTask
@@ -60,11 +61,20 @@ def test_tuner_moves_on_synthetic_plateau():
     assert all(m.beta_thre == task.tuner.ladder[m.pos] for m in task.moves)
 
 
-def test_elastic_run_ladder_interleave_and_zero_retraces(tmp_path):
+def test_elastic_run_ladder_interleave_and_zero_retraces(tmp_path,
+                                                         monkeypatch):
+    # the launch counter below the dispatch layer   # repro-lint: disable=REP002
+    from repro.kernels import cluster_attention as _ca
+
+    # the sparse step runs the Pallas cluster kernels (interpret mode),
+    # whose grid bound is the layout's live count
+    monkeypatch.setenv("REPRO_FORCE_PALLAS_CLUSTER", "interpret")
     cfg, task = _mk_task()
     tr = _mk_trainer(cfg, task, tmp_path / "ck")
+    launches = _ca.pallas_call_count()
     state, status = tr.run()
     assert status == "done"
+    assert _ca.pallas_call_count() > launches   # forward, dQ, dK/dV traced
     # >= 1 AutoTuner ladder move happened inside the trainer loop and the
     # served layout followed it
     assert len(task.moves) >= 1
@@ -75,8 +85,13 @@ def test_elastic_run_ladder_interleave_and_zero_retraces(tmp_path):
         want = use_dense_step(h["step"] - 1, 5, task.conditions_ok)
         assert h["dense"] == want, h
     assert sum(1 for h in tr.history if h["dense"]) >= 1
+    # the sparse steps ran on layouts whose live counts differ, so the
+    # kernels' grid bound changed value between steps
+    live = {grid_steps(task._preps[h["beta_thre"]][0].layout.block_idx)[0]
+            for h in tr.history if not h["dense"]}
+    assert len(live) >= 2, live
     # exactly two traces for the whole run (sparse + dense), despite the
-    # re-layouts: shapes never changed
+    # re-layouts: shapes never changed, and neither did the grid's shape
     assert tr._step._cache_size() == 1
     assert tr._step_dense._cache_size() == 1
 

@@ -168,18 +168,28 @@ def test_grid_audit_passes_real_cluster_triple():
     grid_triple with a concrete scalar-prefetch block index."""
     from repro.core.reformation import lm_local_global_layout
     # auditing the grid contract itself, not bypassing dispatch.  # repro-lint: disable=REP002
-    from repro.kernels.cluster_attention import grid_triple
+    from repro.kernels.cluster_attention import fwd_stream, grid_triple
 
     lay = lm_local_global_layout(512, bq=64, bk=64, window=128, n_global=64)
     nq, mb = lay.block_idx.shape
-    t = grid_triple(2, 512, 4, 2, 128, nq, mb, bk=64,
+    idx, n = fwd_stream(jnp.asarray(lay.block_idx)[None], interpret=True)
+    prefetch = (np.asarray(idx), lay.block_idx.reshape(-1))
+    live = int((lay.block_idx >= 0).sum())
+    assert int(n) == live < nq * mb   # the grid runs the live slots only
+    t = grid_triple(2, 512, 4, 2, 128, nq, mb, int(n), bk=64,
                     return_residuals=True)
-    idx = np.broadcast_to(np.asarray(lay.block_idx, np.int32)[None],
-                          (2, nq, mb)).reshape(-1)  # flat prefetch stream
+    assert t["grid"] == (2, 4, live)
     fs = audit_grid(t["grid"], t["in_specs"], t["out_specs"],
-                    t["in_shapes"], t["out_shapes"], scalar_prefetch=(idx,),
-                    label="cluster fwd")
+                    t["in_shapes"], t["out_shapes"],
+                    scalar_prefetch=prefetch, label="cluster fwd")
     assert not errors(fs), [str(f) for f in fs]
+    # a bound short of the last q-row's entries leaves that row unwritten
+    short = live - int((lay.block_idx[-1] >= 0).sum())
+    t = grid_triple(2, 512, 4, 2, 128, nq, mb, short, bk=64)
+    fs = audit_grid(t["grid"], t["in_specs"], t["out_specs"],
+                    t["in_shapes"], t["out_shapes"],
+                    scalar_prefetch=prefetch, label="cluster fwd")
+    assert any("never written" in f.message for f in fs)
 
 
 def test_ops_dispatch_grid_audit_accepts_good_layout():

@@ -98,6 +98,130 @@ def test_cluster_full_layout_equals_dense():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
+# ----------------------------------------- the compacted grid, fwd and bwd
+
+_NQ = 8          # S = 64 in blocks of 8
+
+
+def _rows(*rows, mb=None):
+    """A (nq, mb) layout from per-q-row k-block lists, -1 padded."""
+    mb = mb or max(4, max(len(r) for r in rows))
+    out = np.full((len(rows), mb), -1, np.int32)
+    for i, r in enumerate(rows):
+        out[i, :len(r)] = r
+    return out
+
+
+def _diag(i):
+    return [0, i] if i else [0]           # the global column, the diagonal
+
+
+_LAYOUTS = {
+    # the global row lists every k-block, every row visits block 0
+    "global_row": [_rows([*range(_NQ)], *(_diag(i) for i in range(1, _NQ)))],
+    # q-row 3 visits nothing: its output and lse are written as zeros
+    "empty_row": [_rows(*(_diag(i) if i != 3 else [] for i in range(_NQ)))],
+    # nothing visits k-block 5: its dK and dV are exactly zero
+    "unvisited_kblock": [_rows(*(_diag(i) if i != 5 else [0, 4]
+                                 for i in range(_NQ)))],
+    # two graphs whose live counts differ (the grid runs to the larger)
+    "ragged_batch": [_rows([*range(_NQ)], *(_diag(i) for i in range(1, _NQ))),
+                     _rows(*([i] for i in range(_NQ)))],
+    # capacity far above the live count: the padding is never visited
+    "wide_capacity": [_rows(*(_diag(i) for i in range(_NQ)), mb=32)],
+}
+
+
+@pytest.mark.parametrize("case,rewrite", [
+    ("global_row", "plain"), ("global_row", "fuse_bias+hoist_scale"),
+    ("empty_row", "biased"), ("empty_row", "hoist_scale"),
+    ("unvisited_kblock", "plain"),
+    ("unvisited_kblock", "fuse_bias+hoist_scale"),
+    ("ragged_batch", "biased"), ("wide_capacity", "hoist_scale"),
+])
+def test_compacted_grid_matches_reference(case, rewrite):
+    """The forward, dQ and dK/dV kernels walk only the live slots (plus
+    one dead entry per empty q-row or unvisited k-block), and agree with
+    the jnp reference and its autodiff on every layout shape the
+    compaction has to get right."""
+    # the reference of the kernels' math, below the dispatch layer
+    from repro.core.dual_attention import cluster_sparse_attention
+    from repro.core.reformation import grid_steps, transpose_block_idx
+    from repro.kernels.cluster_attention import (  # repro-lint: disable=REP002
+        dkv_stream, fwd_stream)
+    from repro.kernels.cluster_attention_bwd import (  # repro-lint: disable=REP002
+        cluster_attention_vjp)
+
+    graphs = _LAYOUTS[case]
+    B, S, H, KV, Dh, bq = len(graphs), 8 * _NQ, 4, 2, 16, 8
+    mb = max(g.shape[1] for g in graphs)
+    bi = np.stack([np.pad(g, ((0, 0), (0, mb - g.shape[1])),
+                          constant_values=-1) for g in graphs])
+    ts = [transpose_block_idx(g, _NQ) for g in bi]
+    mt = 32 if case == "wide_capacity" else max(t.shape[1] for t in ts)
+    bit = np.stack([np.pad(t, ((0, 0), (0, mt - t.shape[1]), (0, 0)),
+                           constant_values=-1) for t in ts])
+    biased = "bias" in rewrite
+    rng = np.random.default_rng(3)
+    bu = bt = None
+    if biased:
+        bu = jnp.asarray(np.where(bi[..., None, None] >= 0, rng.integers(
+            -1, 3, bi.shape + (bq, bq)), -1).astype(np.int8))
+        bt = jax.random.normal(jax.random.fold_in(KEY, 9), (H, 3)) * 0.3
+    q, k, v = (jax.random.normal(jax.random.fold_in(KEY, i), shape)
+               for i, shape in enumerate([(B, S, H, Dh), (B, S, KV, Dh),
+                                          (B, S, KV, Dh)]))
+    g = jax.random.normal(jax.random.fold_in(KEY, 4), (B, S, H, Dh))
+    bi_j, bit_j = jnp.asarray(bi), jnp.asarray(bit)
+
+    # the grid bounds are the live counts, far under the rectangle, and
+    # the streams visit each graph's live slots in the layout's order
+    n, n_t = grid_steps(bi, bit)
+    fwd, n_f = fwd_stream(bi_j, interpret=True)
+    dkv, n_b = dkv_stream(bit_j, interpret=True)
+    assert int(n_f) == n <= _NQ * _NQ and int(n_b) == n_t
+    if case == "wide_capacity":
+        assert n < _NQ * mb // 4 and n_t < _NQ * mt // 4
+    for g in range(B):
+        want = [(r, m) for r in range(_NQ)
+                for m in (np.flatnonzero(bi[g, r] >= 0) if (bi[g, r] >= 0).any()
+                          else [0])]
+        got = np.asarray(fwd).reshape(B, -1)[g, :len(want)]
+        assert [(w >> 13 & 8191, w & 8191) for w in got.tolist()] == want
+        want = [tuple(v) for j in range(_NQ)
+                for v in (bit[g, j][bit[g, j, :, 0] >= 0].tolist() or [[j, 0]])]
+        got = np.asarray(dkv).reshape(B, -1)[g, :len(want)]
+        assert [(w >> 13 & 8191, w & 8191) for w in got.tolist()] == want
+
+    def kernel(q, k, v, bt):
+        o = cluster_attention_vjp(
+            q, k, v, bi_j, bu, bt, bit_j, interpret=True,
+            hoist_scale="hoist" in rewrite, fuse_bias="fuse" in rewrite)
+        return (o * g).sum()
+
+    def reference(q, k, v, bt):
+        o = cluster_sparse_attention(q, k, v, bi_j, bu, bt, bq=bq, bk=bq)
+        return (o * g).sum()
+
+    argnums = (0, 1, 2, 3) if biased else (0, 1, 2)
+    val, got = jax.value_and_grad(kernel, argnums)(q, k, v, bt)
+    ref, want = jax.value_and_grad(reference, argnums)(q, k, v, bt)
+    np.testing.assert_allclose(val, ref, rtol=2e-5, atol=2e-4)
+    for name, a, b in zip(("dq", "dk", "dv", "dbias"), got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4,
+                                   rtol=2e-4, err_msg=name)
+    if case == "unvisited_kblock":
+        blk = slice(5 * bq, 6 * bq)
+        assert not np.asarray(got[1])[:, blk].any()
+        assert not np.asarray(got[2])[:, blk].any()
+    if case == "empty_row":
+        o, lse = cluster_attention(q, k, v, bi_j, bu, bt, interpret=True,
+                                   return_residuals=True)
+        row = slice(3 * bq, 4 * bq)
+        assert not np.asarray(o)[:, row].any()
+        assert not np.asarray(lse).reshape(B, H, S)[:, :, row].any()
+
+
 @pytest.mark.parametrize("B,S,H,dh,N,Q", [
     (2, 128, 3, 32, 16, 32),
     (1, 64, 2, 16, 8, 16),

@@ -284,3 +284,49 @@ def test_graph_level_prep_seconds_is_its_span():
                  "repro.prep.encodings": 4, "repro.prep.layout": 2 * rungs,
                  "repro.prep.pack": 2, "repro.prep.pad": 2}
     assert task.batches(0)["lap_pe"].shape[-1] == 8
+
+
+@pytest.mark.parametrize("kind", ["node", "graph_level"])
+def test_layout_slot_counters_equal_the_kernels_bound(kind):
+    """``layout.live_slots`` / ``layout.live_slots_t`` under each rung's
+    ``repro.prep.layout`` span are the grid bounds the cluster kernels
+    compute in-trace from that rung's (padded) batch, and
+    ``layout.rect_slots`` under ``repro.prep.pad`` the rectangle."""
+    from repro.core.graph import sbm_graph
+    # the kernels' own stream builders   # repro-lint: disable=REP002
+    from repro.kernels.cluster_attention import dkv_stream, fwd_stream
+    from repro.runtime import spans
+    from repro.tasks import (GraphLevelTask, NodeTask,
+                             synthetic_graph_level_dataset)
+
+    with spans.recording() as rec:
+        if kind == "node":
+            cfg = get_smoke_config("graphormer_slim")
+            task = NodeTask(sbm_graph(96, 4, p_in=0.05, p_out=0.003,
+                                      feat_dim=cfg.feat_dim,
+                                      n_classes=cfg.n_classes, seed=0),
+                            cfg, bq=16, bk=16, d_b=8)
+        else:
+            cfg = get_smoke_config("gt")
+            graphs = synthetic_graph_level_dataset(3, cfg, seed=1, n_lo=20,
+                                                   n_hi=60)
+            task = GraphLevelTask(graphs, cfg, delta=2)
+
+    def counter(span_, name):
+        return rec.counters[(span_["id"], name)]
+
+    layouts = [s for s in rec.spans if s["name"] == "repro.prep.layout"]
+    assert len(layouts) == len(dict.fromkeys(task.tuner.ladder))
+    lives = set()
+    for s in layouts:
+        b = task._preps[s["attrs"]["beta_thre"]][0].batch
+        n = int(fwd_stream(jnp.asarray(b["block_idx"]), interpret=True)[1])
+        n_t = int(dkv_stream(jnp.asarray(b["block_idx_t"]),
+                             interpret=True)[1])
+        assert counter(s, "layout.live_slots") == n
+        assert counter(s, "layout.live_slots_t") == n_t
+        lives.add(n)
+    nq, mb = task.prep.batch["block_idx"].shape[-2:]
+    pad = [s for s in rec.spans if s["name"] == "repro.prep.pad"]
+    assert [counter(s, "layout.rect_slots") for s in pad] == [nq * mb]
+    assert max(lives) < nq * mb

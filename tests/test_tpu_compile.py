@@ -23,6 +23,7 @@ from pathlib import Path
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
@@ -159,6 +160,46 @@ def test_cluster_kernel_compiles(one_chip, slim, op, layout, variant):
                             else (0, 1, 2))(q, k, v, bt)
 
     compiled = jax.jit(f).lower(q, q, q, bi, bu, bt, bit).compile()
+    assert "tpu_custom_call" in _fits(compiled)
+
+
+@pytest.mark.parametrize("op", ["fwd", "grad"])
+def test_compacted_kernels_compile_at_cell_size(one_chip, op):
+    """The benchmark cells' shape: S = 6,944 in blocks of 32 with one
+    global row that lists all 217 k-blocks (``mb = mt = 220``), the
+    others nearly empty. Each call's scalar prefetch (its stream and
+    ``block_idx``, a word a slot each: 381,920 bytes) and the stream
+    builder's SMEM must fit, and the live count must pass Mosaic as a
+    dynamic grid bound, forward and through ``jax.grad``."""
+    from repro.core.reformation import transpose_block_idx
+
+    S, H, bq, nq = 6944, 8, 32, 217
+    bi = np.full((nq, 220), -1, np.int32)
+    bi[0, :nq] = np.arange(nq)
+    bi[1:, 0], bi[1:, 1] = 0, np.arange(1, nq)
+    bit = transpose_block_idx(bi, nq)
+    assert bit.shape == (nq, 220, 2)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    q = sds((1, S, H, kops.LANE), jnp.bfloat16)
+    args = (q, q, q, sds((1,) + bi.shape, jnp.int32),
+            sds((1,) + bi.shape + (bq, bq), jnp.int8), sds((H, 3), jnp.float32),
+            sds((1,) + bit.shape, jnp.int32))
+
+    if op == "fwd":
+        def f(q, k, v, bi, bu, bt, bit):
+            return cluster_attention(q, k, v, bi, bu, bt,
+                                     return_residuals=True)
+    else:
+        def f(q, k, v, bi, bu, bt, bit):
+            def loss(q, k, v, bt):
+                o = cluster_attention_vjp(q, k, v, bi, bu, bt, bit)
+                return o.astype(jnp.float32).sum()
+            return jax.grad(loss, argnums=(0, 1, 2, 3))(q, k, v, bt)
+
+    compiled = jax.jit(f).lower(*args).compile()
     assert "tpu_custom_call" in _fits(compiled)
 
 
